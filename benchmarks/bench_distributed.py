@@ -128,6 +128,9 @@ def main():
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n_dev = len(jax.devices())
     counts = [c for c in args.devices if c <= n_dev]
     if len(counts) < 2:

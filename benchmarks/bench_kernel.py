@@ -116,18 +116,22 @@ def run(smoke: bool = False) -> list[str]:
         rows.append(f"kernel.layer_{name}_planes_used,"
                     f"{used.mean():.3f},skipped={float(st.skipped_frac):.4f}")
 
-    # pallas interpret-mode parity check at bench scale, tiled K (the kernel
-    # consumes quantized activations and encodes digits in-kernel; the
-    # oracle evaluates over an explicitly materialized plane tensor)
+    # pallas parity check at bench scale, tiled K (the kernel consumes
+    # quantized activations and encodes digits in-kernel; the oracle
+    # evaluates over an explicitly materialized plane tensor).  Interpreted
+    # off-TPU at small blocks; compiled on a TPU at the blocks Mosaic takes.
     from repro.kernels.ref import make_planes, dslot_matmul_ref
     from repro.kernels.dslot_matmul import dslot_matmul_pallas
-    aq = jnp.asarray(rng.integers(0, 256, (64, 64)), jnp.int32)
-    wp = jnp.asarray(rng.normal(0, 0.05, (64, 64)), jnp.float32)
-    o1 = dslot_matmul_pallas(aq, wp, block_m=32, block_n=32,
-                             block_k=32).out
+    tpu = jax.default_backend() == "tpu"
+    n, blk = (256, 128) if tpu else (64, 32)
+    aq = jnp.asarray(rng.integers(0, 256, (n, n)), jnp.int32)
+    wp = jnp.asarray(rng.normal(0, 0.05, (n, n)), jnp.float32)
+    o1 = dslot_matmul_pallas(aq, wp, block_m=blk, block_n=blk,
+                             block_k=blk).out
     o2 = dslot_matmul_ref(make_planes(aq, 8), wp, 8)
     rows.append(f"kernel.pallas_vs_ref_maxerr,"
-                f"{float(jnp.abs(o1 - o2).max()):.2e},interpret-tiled-k")
+                f"{float(jnp.abs(o1 - o2).max()):.2e},"
+                f"{'compiled' if tpu else 'interpret'}-tiled-k")
     return rows
 
 
@@ -548,6 +552,8 @@ def run_precision_sweep(smoke: bool = False) -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes (CI smoke job)")

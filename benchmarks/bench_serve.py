@@ -500,6 +500,8 @@ def run_chaos_mesh(prompt_len: int, chunk: int, n_slots: int, max_new: int,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes / few reps for CI")

@@ -14,7 +14,10 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import bench_cycles, bench_kernel, bench_mnist_stats, bench_table1
+    enable_compile_cache()
     sections = [
         ("table1", bench_table1.run),
         ("cycles", bench_cycles.run),

@@ -37,9 +37,10 @@ def main():
     res = generate(model, params, batch, 8)
     print("enc-dec batched generation:", res.tokens.shape)
 
-    # ---- DSLOT digit-serial MLPs (ReLU FFN -> early termination applies)
+    # ---- DSLOT digit-serial MLPs (ReLU FFN -> early termination applies),
+    # at the 128x128 blocks the compiled TPU kernel takes
     dcfg = dataclasses.replace(cfg, dslot=DslotConfig(
-        enabled=True, n_planes=8, block_m=16, block_n=16))
+        enabled=True, n_planes=8))
     dmodel = build_model(dcfg)
     dparams = dmodel.prepare_dslot(params)      # weight-stationary lowering,
     res2 = generate(dmodel, dparams, batch, 8)  # done once for all requests
@@ -108,8 +109,7 @@ def main():
     # and restores the planes once the queue drains.
     scfg = dataclasses.replace(
         lcfg, act="relu", glu=False,
-        dslot=DslotConfig(enabled=True, block_m=16, block_n=32, block_k=16,
-                          act_scale=0.05))
+        dslot=DslotConfig(enabled=True, act_scale=0.05))
     smodel = build_model(scfg)
     sparams = smodel.init(jax.random.PRNGKey(3))
     eng2 = ServeEngine(smodel, sparams, ServeConfig(

@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.mlp import _ACTS
@@ -135,12 +134,8 @@ def apply_moe_ep(p, x, cfg, mesh: Mesh, axis: str = "model",
     # model rank holds the same tokens); the static vma checker cannot prove
     # data-dependent replication, so it is disabled.
     in_specs = (P(bspec), P(), P(axis), P(axis), P(axis), P(axis))
-    try:
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs,
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=(P(bspec), P(axis)), check_vma=False)
-    except TypeError:                                  # older kwarg name
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=(P(bspec), P(axis)), check_rep=False)
     y, aux = sm(x, p["router"], p["up"], p.get("gate", p["up"]), p["down"],
                 planes_all)
     return y, jnp.mean(aux)

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -36,9 +35,8 @@ def collective_matmul_ag(x, w, mesh: Mesh, axis: str = "model"):
     def body(xl, wl):                       # xl: (S/n, K), wl: (K, N/n)
         idx = jax.lax.axis_index(axis)
         s_local = xl.shape[0]
-        y0 = jnp.zeros((s_local * n, wl.shape[1]), jnp.float32)
-        if hasattr(jax.lax, "pvary"):       # newer jax: mark device-varying
-            y0 = jax.lax.pvary(y0, (axis,))
+        y0 = jax.lax.pvary(jnp.zeros((s_local * n, wl.shape[1]), jnp.float32),
+                           (axis,))         # device-varying accumulator
         # device i sends to i-1: after r rounds, device d holds slice (d+r)%n
         perm = [(i, (i - 1) % n) for i in range(n)]
 
@@ -55,9 +53,9 @@ def collective_matmul_ag(x, w, mesh: Mesh, axis: str = "model"):
                                  jnp.arange(n, dtype=jnp.int32))
         return y
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis, None), P(None, axis)),
-                     out_specs=P(None, axis))(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis, None), P(None, axis)),
+                         out_specs=P(None, axis))(x, w)
 
 
 def plain_matmul_ag(x, w, mesh: Mesh, axis: str = "model"):
@@ -68,6 +66,6 @@ def plain_matmul_ag(x, w, mesh: Mesh, axis: str = "model"):
         return jnp.einsum("sk,kn->sn", xg.astype(jnp.float32),
                           wl.astype(jnp.float32))
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis, None), P(None, axis)),
-                     out_specs=P(None, axis))(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis, None), P(None, axis)),
+                         out_specs=P(None, axis))(x, w)

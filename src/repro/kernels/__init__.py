@@ -3,8 +3,8 @@
 ``dslot_matmul.py`` — pl.pallas_call kernel (fused in-kernel MSDF digit
 encoding straight from the quantized activation block — no materialized
 (D, M, K) plane tensor — K-chunked VMEM streaming with a chunk-aware
-per-tile early-termination bound, SMEM runtime precision scalar + per-row
-budget vector + static per-N-tile weight-side MSR plane bound, auto
+per-tile early-termination bound, SMEM runtime precision scalar, VMEM per-row
+budget column, SMEM static per-N-tile weight-side MSR plane bound, auto
 block-size selection, bf16 weights, batched entry);
 ``ops.py`` — jit'd wrapper with quantization / padding / column-sorting and
 a jnp backend replaying identical termination accounting plane-free;

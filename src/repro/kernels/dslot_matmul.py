@@ -1,7 +1,7 @@
 """Pallas TPU kernel: digit-serial MSDF matmul with fused in-kernel digit
 encoding and per-tile early termination.
 
-TPU-native adaptation of DSLOT-NN's datapath (DESIGN.md §2/§4.2).  The FPGA
+TPU-native adaptation of DSLOT-NN's datapath (``docs/kernel.md``).  The FPGA
 design streams one signed digit per cycle through online multipliers and kills
 a SOP the moment its MSDF prefix goes negative.  A TPU has no per-lane early
 exit, so the unit of "digit" becomes a *digit plane* (one MXU matmul) and the
@@ -40,7 +40,7 @@ only terminate a tile at the same plane or an earlier one.
 
 Runtime precision is two-level: ``n_planes_rt`` (i32 scalar in SMEM)
 predicates whole planes off for the entire call, and ``row_budget`` (i32
-per-row vector, one ``(block_m,)`` SMEM block per M-tile) zeroes digits
+per-row vector, one ``(block_m, 1)`` VMEM block per M-tile) zeroes digits
 beyond each row's own budget inside the extraction — per-request precision
 in a serving batch without masking work outside the kernel.  Both are
 runtime values: changing precision never retraces.
@@ -66,8 +66,9 @@ because ``M % block_m == 0``), which is exactly equivalent to a vmap but
 keeps a single sequential grid, and forwards the prepared termination tables
 and runtime precision of the unbatched entry.
 
-Validated in interpret mode against ``ref.dslot_matmul_ref`` (CPU container);
-targeted at TPU v5e.
+Validated in interpret mode against ``ref.dslot_matmul_ref`` on CPU, and
+compiled and run on a TPU v5e (``chip_smoke.py``; the Mosaic layout rules the
+operands follow are in ``docs/kernel.md``).
 """
 
 from __future__ import annotations
@@ -129,7 +130,7 @@ def select_block_k(K: int, block_m: int, block_n: int, w_itemsize: int,
     (bm, bk) x ``act_itemsize`` (the integer ``q`` block digits are derived
     from — there is no separate plane chunk), one weight chunk (bk, bn), the
     f32 accumulator + output tile (bm, bn) and two f32 colsum rows (bn); the
-    SMEM scalars (runtime precision, per-row budgets, termination flag) are
+    runtime precision, per-row budgets and termination flag are
     negligible.  Returns K itself when the whole reduction fits (the untiled
     fast path — which also makes the ``q`` chunk resident across all D
     planes); otherwise a lane-aligned chunk size.
@@ -172,23 +173,25 @@ def _kernel(npl_ref, bnd_ref, bud_ref, q_ref, w_ref, sfx_ref, tot_ref,
     d = pl.program_id(2)
     c = pl.program_id(3)
 
+    j = pl.program_id(1)
+
     @pl.when(jnp.logical_and(d == 0, c == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         term_ref[0] = 0
-        used_ref[...] = jnp.zeros_like(used_ref)
+        used_ref[0, j] = 0
 
     # Runtime precision: planes at d >= npl are skipped entirely (their MXU
     # pass AND their digit extraction are predicated off), so precision is a
     # per-call argument — changing it never retraces or re-lowers the kernel.
-    # The static per-N-tile MSR bound (SMEM scalar per j, baked at
+    # The static per-N-tile MSR bound (SMEM table indexed by j, baked at
     # dslot_prepare time from weight-side analysis — core.msr) caps the
     # plane count the same way: the effective plane budget of this tile is
     # min(n_planes_rt, row_budget, msr_bound[j]), so weight-inert tiles
     # never extract digits or issue MXU passes at all.
     npl = npl_ref[0, 0]
     terminated = jnp.logical_or(jnp.logical_or(term_ref[0] > 0, d >= npl),
-                                d >= bnd_ref[0, 0])
+                                d >= bnd_ref[0, j])
 
     @pl.when(jnp.logical_not(terminated))
     def _accumulate():
@@ -199,10 +202,10 @@ def _kernel(npl_ref, bnd_ref, bud_ref, q_ref, w_ref, sfx_ref, tot_ref,
         bit = (jnp.abs(q) >> (n_bits - 1 - d)) & 1
         digit = (bit * jnp.sign(q)).astype(jnp.float32)
         # Per-row precision: rows whose budget is exhausted contribute zero
-        # digits from this plane on (the SMEM (block_m,) budget vector of
+        # digits from this plane on (the (block_m, 1) VMEM budget column of
         # this M-tile) — per-request precision inside a pooled batch.
-        live = (bud_ref[0, :] > d).astype(jnp.float32)     # (bm,)
-        plane = digit * live[:, None]
+        live = (bud_ref[...] > d).astype(jnp.float32)      # (bm, 1)
+        plane = digit * live
         w = w_ref[...].astype(jnp.float32)                 # (bk, bn)
         scale = jnp.exp2(jnp.asarray(n_bits - 1, jnp.float32)
                          - d.astype(jnp.float32))
@@ -211,7 +214,7 @@ def _kernel(npl_ref, bnd_ref, bud_ref, q_ref, w_ref, sfx_ref, tot_ref,
 
         @pl.when(c == 0)
         def _count_plane():
-            used_ref[0, 0] += 1
+            used_ref[0, j] += 1
 
         if relu:
             # Chunk-aware remaining-contribution bound (module docstring):
@@ -219,9 +222,9 @@ def _kernel(npl_ref, bnd_ref, bud_ref, q_ref, w_ref, sfx_ref, tot_ref,
             # the runtime precision npl (geometric tail 2^(n_bits - npl)).
             tail = jnp.exp2(jnp.asarray(n_bits, jnp.float32)
                             - npl.astype(jnp.float32))
-            rem = scale * sfx_ref[0] \
-                + (scale - tail) * tot_ref[0]              # (bn,)
-            provably_neg = jnp.all(acc_ref[...] + rem[None, :] < 0.0)
+            rem = scale * sfx_ref[...] \
+                + (scale - tail) * tot_ref[...]            # (1, bn)
+            provably_neg = jnp.all(acc_ref[...] + rem < 0.0)
             term_ref[0] = jnp.where(provably_neg, 1, term_ref[0])
 
     @pl.when(jnp.logical_and(d == n_planes - 1, c == n_kchunks - 1))
@@ -231,6 +234,31 @@ def _kernel(npl_ref, bnd_ref, bud_ref, q_ref, w_ref, sfx_ref, tot_ref,
             acc = jnp.maximum(acc, 0.0)
             acc = jnp.where(term_ref[0] > 0, 0.0, acc)
         out_ref[...] = acc
+
+
+def _check_tpu_blocks(block_m: int, block_n: int, block_k: int,
+                      Kp: int) -> None:
+    """Refuse, by name, a block the compiled kernel cannot lower.
+
+    Mosaic needs the last two dims of every block to be multiples of
+    (8, 128) or to span the array: ``block_m`` is the sublane dim of the
+    activation, output and budget blocks, ``block_n`` the lane dim of the
+    weight, output and colsum blocks, and a K chunk smaller than the padded
+    K is the lane dim of the activation block.  Interpret mode has no such
+    rule, so the CPU tests keep their small blocks.
+    """
+    bad = []
+    if block_m % 8:
+        bad.append(f"block_m={block_m} (needs a multiple of 8)")
+    if block_n % _LANE:
+        bad.append(f"block_n={block_n} (needs a multiple of {_LANE})")
+    if block_k != Kp and block_k % _LANE:
+        bad.append(f"block_k={block_k} (needs a multiple of {_LANE}, or "
+                   f"the whole padded K={Kp})")
+    if bad:
+        raise ValueError("compiled TPU kernel cannot tile "
+                         + ", ".join(bad) + "; interpret mode accepts any "
+                         "block")
 
 
 def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
@@ -255,7 +283,7 @@ def dslot_matmul_pallas(q: jax.Array, w: jax.Array, *, n_bits: int = 8,
                         suffix_colsum: jax.Array | None = None,
                         total_colsum: jax.Array | None = None,
                         plane_bound: jax.Array | None = None,
-                        interpret: bool = True) -> DslotMatmulOut:
+                        interpret: bool | None = None) -> DslotMatmulOut:
     """Run the digit-serial matmul kernel with fused digit encoding.
 
     q:       (M, K) integer quantized activations, |q| < 2^n_bits (see
@@ -273,8 +301,8 @@ def dslot_matmul_pallas(q: jax.Array, w: jax.Array, *, n_bits: int = 8,
              d >= n_planes_rt are predicated off — no retrace across
              precisions.  None runs all D planes.
     row_budget: optional RUNTIME per-row precision ((M,) i32): digits of row
-             m beyond ``row_budget[m]`` are zeroed during extraction (SMEM
-             (block_m,) vector per M-tile).  The scalar ``n_planes_rt``
+             m beyond ``row_budget[m]`` are zeroed during extraction (a
+             (block_m, 1) VMEM column per M-tile).  The scalar ``n_planes_rt``
              still bounds the whole call — pass the row max (as
              ``ops.dslot_execute`` does) so fully-exhausted planes skip
              their passes.  None means every row runs to ``n_planes_rt``.
@@ -288,7 +316,12 @@ def dslot_matmul_pallas(q: jax.Array, w: jax.Array, *, n_bits: int = 8,
              baked at prepare time (``core.msr.tile_plane_bound`` emits
              only output-exact bounds).  Rides in SMEM like the runtime
              precision scalar; None means no weight-side cap.
+    interpret: run the Pallas interpreter instead of compiling with Mosaic.
+             None (default) interprets only off-TPU, so a TPU caller always
+             gets the compiled kernel.
     M % block_m == 0 and N % block_n == 0 (callers pad — see ``ops.py``).
+    Compiled, the blocks must also meet the TPU tiling rule
+    (``_check_tpu_blocks``); interpret mode takes any block size.
     """
     M, K = q.shape
     K2, N = w.shape
@@ -311,28 +344,38 @@ def dslot_matmul_pallas(q: jax.Array, w: jax.Array, *, n_bits: int = 8,
     w = _pad_to(w, bk, axis=0)
     Kp = w.shape[0]
     Kt = Kp // bk
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if not interpret:
+        _check_tpu_blocks(block_m, block_n, bk, Kp)
 
     if suffix_colsum is None or total_colsum is None:
         suffix_colsum, total_colsum = colsum_tables(w, bk)
     assert suffix_colsum.shape == (Kt, N), (suffix_colsum.shape, Kt, N)
     assert total_colsum.shape == (1, N), (total_colsum.shape, N)
 
+    Mt, Nt = M // block_m, N // block_n
     if n_planes_rt is None:
         n_planes_rt = jnp.asarray(D, jnp.int32)
     npl = jnp.asarray(n_planes_rt, jnp.int32).reshape(1, 1)
     if plane_bound is None:
-        bnd = jnp.full((1, N // block_n), D, jnp.int32)
+        bnd = jnp.full((1, Nt), D, jnp.int32)
     else:
-        assert plane_bound.shape == (N // block_n,), \
-            (plane_bound.shape, N, block_n)
-        bnd = jnp.asarray(plane_bound, jnp.int32).reshape(1, -1)
+        assert plane_bound.shape == (Nt,), (plane_bound.shape, N, block_n)
+        bnd = jnp.asarray(plane_bound, jnp.int32).reshape(1, Nt)
     if row_budget is None:
-        bud = jnp.full((1, M), npl[0, 0], jnp.int32)
+        bud = jnp.full((M, 1), npl[0, 0], jnp.int32)
     else:
         assert row_budget.shape == (M,), (row_budget.shape, M)
-        bud = jnp.asarray(row_budget, jnp.int32).reshape(1, M)
+        bud = jnp.asarray(row_budget, jnp.int32).reshape(M, 1)
 
-    grid = (M // block_m, N // block_n, D, Kt)
+    # Layouts Mosaic accepts: the last two block dims are (8, 128)-aligned
+    # or span the array.  The plane-bound table is one whole SMEM block read
+    # at [0, j]; planes_used is written one (1, Nt) SMEM row per M-tile (the
+    # block stays resident across the j, d, c steps of that row), so SMEM
+    # holds O(Nt) words at any M; the row budget is a (block_m, 1) VMEM
+    # column; the suffix table gains a unit axis so its block is (1, bn).
+    grid = (Mt, Nt, D, Kt)
     kernel = functools.partial(_kernel, n_bits=n_bits, n_planes=D,
                                n_kchunks=Kt, relu=relu)
     out, used = pl.pallas_call(
@@ -341,30 +384,30 @@ def dslot_matmul_pallas(q: jax.Array, w: jax.Array, *, n_bits: int = 8,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j, d, c: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, d, c: (0, j),
+            pl.BlockSpec((1, Nt), lambda i, j, d, c: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_m), lambda i, j, d, c: (0, i),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_m, 1), lambda i, j, d, c: (i, 0)),
             pl.BlockSpec((block_m, bk), lambda i, j, d, c: (i, c)),
             pl.BlockSpec((bk, block_n), lambda i, j, d, c: (c, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, d, c: (c, j)),
+            pl.BlockSpec((None, 1, block_n), lambda i, j, d, c: (c, 0, j)),
             pl.BlockSpec((1, block_n), lambda i, j, d, c: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((block_m, block_n), lambda i, j, d, c: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, d, c: (i, j)),
+            pl.BlockSpec((None, 1, Nt), lambda i, j, d, c: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), jnp.float32),
-            jax.ShapeDtypeStruct((M // block_m, N // block_n), jnp.int32),
+            jax.ShapeDtypeStruct((Mt, 1, Nt), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_m, block_n), jnp.float32),   # accumulator
             pltpu.SMEM((1,), jnp.int32),                   # termination flag
         ],
         interpret=interpret,
-    )(npl, bnd, bud, q, w, suffix_colsum, total_colsum)
-    return DslotMatmulOut(out=out, planes_used=used)
+    )(npl, bnd, bud, q, w, suffix_colsum.reshape(Kt, 1, N), total_colsum)
+    return DslotMatmulOut(out=out, planes_used=used.reshape(Mt, Nt))
 
 
 def dslot_matmul_pallas_batched(q: jax.Array, w: jax.Array, *,
@@ -378,7 +421,8 @@ def dslot_matmul_pallas_batched(q: jax.Array, w: jax.Array, *,
                                 suffix_colsum: jax.Array | None = None,
                                 total_colsum: jax.Array | None = None,
                                 plane_bound: jax.Array | None = None,
-                                interpret: bool = True) -> DslotMatmulOut:
+                                interpret: bool | None = None
+                                ) -> DslotMatmulOut:
     """Batched entry point: q (B, M, K) sharing one weight matrix.
 
     The batch axis is folded into M — with ``M % block_m == 0`` every output

@@ -18,7 +18,7 @@ the paper's weight-stationary dataflow:
   kernel reads is the ~n_bits/8-byte-per-element ``q`` itself, not D digit
   planes of it.  ``n_planes`` is a RUNTIME argument (scalar or per-row
   vector): planes beyond it are predicated off in the Pallas kernel / masked
-  in the jnp replay (per-row budgets travel as an SMEM vector into the
+  in the jnp replay (per-row budgets travel as a VMEM column into the
   kernel), so changing precision never retraces — this is the paper's
   "precision tuned at run-time" as a first-class request parameter.
 * ``calibrate_scale(x_sample, ...)`` — one-shot activation-range calibration;
@@ -72,8 +72,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.msr import tile_plane_bound
@@ -277,7 +276,7 @@ def _jnp_path(q: jax.Array, w: jax.Array, n_bits: int, n_planes: int,
     contribute nothing and ``planes_used`` is clamped to it — the same
     semantics as the kernel's predicated passes.  ``row_budget`` ((M,) i32)
     zeroes each row's digits beyond its own budget — identical to the
-    kernel's SMEM per-row budget vector.  ``tile_bound`` ((Nt,) i32) is the
+    kernel's per-row budget column.  ``tile_bound`` ((Nt,) i32) is the
     static weight-side MSR plane bound: columns of tile j accumulate
     nothing at d >= tile_bound[j] and the tile's planes_used is capped by
     it — the mirror of the kernel's per-j SMEM bound scalar (a frozen tile
@@ -370,7 +369,7 @@ def _run_backend(cfg: DslotWeights, q_p: jax.Array, w: jax.Array,
             block_m=cfg.block_m, block_n=cfg.block_n, block_k=cfg.block_k,
             n_planes_rt=npl_scalar, row_budget=bud_p,
             suffix_colsum=suffix, total_colsum=total,
-            plane_bound=bnd, interpret=jax.default_backend() != "tpu")
+            plane_bound=bnd)
         return out_p, jnp.minimum(used, npl_scalar.astype(jnp.int32))
     return _jnp_path(q_p, w, cfg.n_bits, D, cfg.relu,
                      cfg.block_m, cfg.block_n, cfg.block_k,
@@ -411,15 +410,16 @@ def _sharded_exec(cfg: DslotWeights, q_p: jax.Array, npl_scalar: jax.Array,
     in_specs = (P(None, axis), P(None, axis), P(None, axis), P(axis),
                 P(), P(), P())
     out_specs = (P(None, axis), P(None, axis))
-    # the pallas backend has no replication rule, so the static vma/rep
-    # checker is disabled (outputs are genuinely axis-sharded anyway)
-    try:
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs,
+    # the pallas backend has no replication rule, so the static vma checker
+    # is disabled (outputs are genuinely axis-sharded anyway)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    except TypeError:                                  # older kwarg name
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
     out_p, used = sm(w_s, sfx_s, tot_s, bnd_s, q_p, bud_p, npl_scalar)
+    # gather the N shards back to every device before the pad slice and the
+    # un-sort gather: on an explicit-axis mesh (``jax.make_mesh``'s default)
+    # neither may index a sharded dimension, and both read across shards
+    full = NamedSharding(mesh, P())
+    out_p, used = (jax.sharding.reshard(a, full) for a in (out_p, used))
     return out_p[:, :Np], used[:, :Nt]
 
 
@@ -437,7 +437,7 @@ def _execute_core(prepared: DslotWeights, x: jax.Array, npl: jax.Array,
     backends as-is (at the narrowest integer width that holds them) and each
     backend derives digit planes on the fly — the paper's online generation,
     not an HBM-materialized encoding.  Per-row budgets ride along as a
-    runtime vector consumed inside the kernel (SMEM per-M-tile) / scan.
+    runtime vector consumed inside the kernel (VMEM per-M-tile) / scan.
     """
     cfg = prepared
     M, K = x.shape

@@ -16,8 +16,11 @@ separate processes (fresh XLA heap each) and accumulate.
 """
 
 # The 512 placeholder devices MUST be configured before jax initializes —
-# these two lines are deliberately the first executable statements.
+# these lines are deliberately the first executable statements.  The tool is
+# compile-only and pinned to the CPU platform, so on a TPU host it never
+# claims the chip from the process that owns it.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
@@ -51,7 +54,7 @@ def _sds(shape, dtype):
 
 def microbatches_for(arch: ModelConfig, shape: ShapeConfig, mesh) -> int:
     """Grad-accumulation depth: per-device microbatch of ~1 sample for the
-    big models bounds saved activations (DESIGN.md §5)."""
+    big models bounds saved activations."""
     if shape.kind != "train":
         return 1
     fsdp, _ = mesh_axes(mesh)

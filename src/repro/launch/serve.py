@@ -5,14 +5,32 @@ execution (the paper's engine as a serving-time switch).
         --batch 4 --max-new 16 [--dslot --n-planes 6]
 
 ``--dslot`` turns on digit-plane execution (with early negative termination)
-for every ReLU MLP; ``--n-planes`` is the runtime precision knob (named like
-the ``generate(..., n_planes=...)`` / ``Request.n_planes`` argument it sets;
-``--planes`` is kept as a hidden alias).
+for every ReLU MLP, at 128x128 blocks: the compiled Pallas kernel on a TPU,
+the jnp replay elsewhere.  ``--n-planes`` is the runtime precision knob
+(named like the ``generate(..., n_planes=...)`` / ``Request.n_planes``
+argument it sets; ``--planes`` is kept as a hidden alias).
 """
 
 import argparse
 import dataclasses
 import time
+
+
+def make_batch(cfg, batch: int, prompt_len: int, key) -> dict:
+    """Seeded generation inputs for ``cfg``: random prompt tokens, plus the
+    stub frontend frames and encoder source embeddings the audio / enc-dec
+    families take."""
+    import jax
+
+    out = {"tokens": jax.random.randint(
+        key, (batch, prompt_len), 0, cfg.vocab_size)}
+    if cfg.frontend:
+        out["frontend"] = jax.random.normal(
+            key, (batch, cfg.frontend_len, cfg.d_model)) * 0.02
+    if cfg.family == "encdec":
+        out["src_embeds"] = jax.random.normal(
+            key, (batch, 8, cfg.d_model)) * 0.02
+    return out
 
 
 def main():
@@ -28,36 +46,31 @@ def main():
     args = ap.parse_args()
 
     import jax
-    import jax.numpy as jnp
 
     from repro.configs.base import DslotConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.configs.registry import get_arch
     from repro.models import stats
     from repro.models.model_zoo import build_model
     from repro.serve.engine import generate
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if args.dslot:
         cfg = dataclasses.replace(cfg, dslot=DslotConfig(
-            enabled=True, n_planes=args.n_planes, block_m=32, block_n=32))
+            enabled=True, n_planes=args.n_planes,
+            use_pallas=jax.default_backend() == "tpu"))
         if cfg.act != "relu" or cfg.glu:
             print(f"note: {cfg.name} has {cfg.act}/glu MLPs — DSLOT early "
-                  "termination applies only to ReLU MLPs (DESIGN.md §6); "
-                  "running the standard path for those layers.")
+                  "termination applies only to ReLU MLPs; running the "
+                  "standard path for those layers.")
 
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    key = jax.random.PRNGKey(1)
-    batch = {"tokens": jax.random.randint(
-        key, (args.batch, args.prompt_len), 0, cfg.vocab_size)}
-    if cfg.frontend:
-        batch["frontend"] = jax.random.normal(
-            key, (args.batch, cfg.frontend_len, cfg.d_model)) * 0.02
-    if cfg.family == "encdec":
-        batch["src_embeds"] = jax.random.normal(
-            key, (args.batch, 8, cfg.d_model)) * 0.02
+    batch = make_batch(cfg, args.batch, args.prompt_len,
+                       jax.random.PRNGKey(1))
 
     t0 = time.time()
     toks = generate(model, params, batch, args.max_new).tokens

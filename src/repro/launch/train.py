@@ -43,6 +43,7 @@ def main():
     from repro.checkpoint.checkpointer import Checkpointer
     from repro.configs.registry import get_arch
     from repro.data.pipeline import TokenPipeline, make_global_batch
+    from repro.launch.mesh import auto_mesh
     from repro.models import pspec
     from repro.models.model_zoo import build_model
     from repro.optim.adamw import AdamWConfig
@@ -59,7 +60,7 @@ def main():
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
         names = ("data", "model")[-len(shape):]
-        mesh = jax.make_mesh(shape, names)
+        mesh = auto_mesh(shape, names)
         pspec.set_mesh(mesh)
 
     state = init_train_state(model, jax.random.PRNGKey(0))

@@ -16,7 +16,7 @@ layers, now built on the **prepare/execute split** (``kernels.ops``):
   precision scope (policy-supplied, possibly a per-row jax array), or the
   layer's static default, in that order.  Changing precision never
   re-prepares weights and never retraces.  Per-row budgets are consumed
-  INSIDE the kernel (SMEM budget vector) and digit planes are derived
+  INSIDE the kernel (a per-row budget column) and digit planes are derived
   in-kernel from the quantized activations — no plane tensor, no
   row-masking pass outside the kernel (see ``kernels/ops.py``).
 

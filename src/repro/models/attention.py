@@ -291,7 +291,7 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
         kv_pos = jnp.arange(Skv, dtype=jnp.int32)
         k = apply_rope(k, kv_pos, cfg.rope_theta)
 
-    # Shard attention across the model axis (DESIGN.md §5 / pspec.py):
+    # Shard attention across the model axis (pspec.py):
     # "kv" shards kv heads; "repeat" duplicates kv to q-heads so the head
     # axis shards evenly (zero attention collectives at a small kv cost).
     scheme = head_scheme(cfg.n_kv_heads, cfg.n_heads)
@@ -392,7 +392,7 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
                                   chunk=cfg.attn_chunk)
         if return_cache:
             # Build the ring cache from the last kept positions (slot =
-            # pos % C; scatter keeps the ring invariant for any C).  The ring
+            # pos % C, for any C).  The ring
             # is sized for the TARGET sequence length (cache_len), not the
             # prompt, so subsequent decode steps never clobber live slots.
             C = Skv if cross else cache_capacity(cfg, cache_len or int(Skv))
@@ -416,16 +416,23 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
                     v=jnp.where(valid[..., None, None], vb, 0),
                     positions=jnp.where(valid, owner, -1))
             else:
+                # The kept positions p0..p0+n_keep-1 are consecutive (the
+                # pass's own arange), so slot s holds kept entry
+                # (s - p0) mod C: a GATHER, like the ragged branch.  The TPU
+                # compiler aborts on a scatter into a zero ring in a program
+                # that also holds a decode step's ring write (``generate``).
                 n_keep = min(C, Skv)
-                keep = slice(Skv - n_keep, Skv)
-                kept_pos = kv_pos[keep].astype(jnp.int32)
-                slots = kept_pos % C
-                zk = jnp.zeros((B, C) + k.shape[2:], k.dtype)
-                pos0 = jnp.full((C,), -1, jnp.int32).at[slots].set(kept_pos)
+                p0 = kv_pos[Skv - n_keep].astype(jnp.int32)
+                j = (jnp.arange(C, dtype=jnp.int32) - p0) % C        # (C,)
+                has = j < n_keep
+                src = Skv - n_keep + jnp.minimum(j, n_keep - 1)
+                kb = jnp.take(k, src, axis=1)
+                vb = jnp.take(v, src, axis=1)
                 new_cache = KVCache(
-                    k=zk.at[:, slots].set(k[:, keep]),
-                    v=zk.at[:, slots].set(v[:, keep]),
-                    positions=jnp.broadcast_to(pos0[None], (B, C)))
+                    k=jnp.where(has[None, :, None, None], kb, 0),
+                    v=jnp.where(has[None, :, None, None], vb, 0),
+                    positions=jnp.broadcast_to(
+                        jnp.where(has, p0 + j, -1)[None], (B, C)))
 
     out = constrain(out, "b", None, "tp", None)
     y = apply_dense(p["wo"], out.reshape(B, S, cfg.n_heads * hd))

@@ -2,7 +2,7 @@
 mode for inference (the paper's technique as a first-class execution option).
 
 When ``cfg.dslot.enabled`` and the activation is ReLU (the only case where the
-early-negative-termination contract holds — DESIGN.md §6), the up-projection
+early-negative-termination contract holds), the up-projection
 matmul runs through the unified ``repro.layers.DslotDense`` API with fused
 ReLU and per-tile early termination.  ``prepare_mlp_dslot`` attaches the
 one-time weight-stationary lowering (``kernels.ops.dslot_prepare``) to every

@@ -83,6 +83,7 @@ from typing import Callable, Iterator
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 import numpy as np
 
 from repro.kernels.ops import DslotWeights
@@ -322,6 +323,11 @@ class ServeEngine:
             # inside the SAME per-step jit, so one engine step still issues
             # exactly one (sharded) forward.
             from repro.models import pspec
+            if any(t != AxisType.Auto for t in self.cfg.mesh.axis_types):
+                raise ValueError(
+                    "ServeConfig.mesh needs Auto axes (the model places "
+                    "activations with sharding constraints); build it with "
+                    "repro.launch.mesh.auto_mesh / make_test_mesh")
             pspec.set_mesh(self.cfg.mesh)
         # one-time weight-stationary lowering: every decode step executes
         # against cached digit-plane tables (no per-call re-encode)
@@ -337,6 +343,13 @@ class ServeEngine:
         self.slo: SloController | None = None if self.cfg.slo is None \
             else SloController(self.n_bits, self.cfg.slo)
         self.state = model.init_decode_state(self.n_slots, self.max_len)
+        if self.cfg.mesh is not None:
+            # weights and the KV pool live on every device of the mesh from
+            # the start: left on the default device, each sharded step would
+            # copy them out of device 0 again
+            everywhere = NamedSharding(self.cfg.mesh, PartitionSpec())
+            self.params = jax.device_put(self.params, everywhere)
+            self.state = jax.device_put(self.state, everywhere)
         self.slot_req: list[Request | None] = [None] * self.n_slots
         self.next_tok = np.zeros(self.n_slots, np.int32)
         self.last_budget: np.ndarray | None = None  # budgets of last decode
