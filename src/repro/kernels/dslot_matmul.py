@@ -87,6 +87,9 @@ __all__ = ["dslot_matmul_pallas", "dslot_matmul_pallas_batched",
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # leave headroom below v5e's ~16 MiB
 _LANE = 128                            # TPU lane width: K-chunk alignment
+# The kernel's name in compiled programs and profiler traces (the HLO
+# custom call is ``%dslot_matmul_pallas.N``): trace readers find it by this.
+KERNEL_NAME = "dslot_matmul_pallas"
 
 
 class DslotMatmulOut(NamedTuple):
@@ -406,6 +409,7 @@ def dslot_matmul_pallas(q: jax.Array, w: jax.Array, *, n_bits: int = 8,
             pltpu.SMEM((1,), jnp.int32),                   # termination flag
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(npl, bnd, bud, q, w, suffix_colsum.reshape(Kt, 1, N), total_colsum)
     return DslotMatmulOut(out=out, planes_used=used.reshape(Mt, Nt))
 

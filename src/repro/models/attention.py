@@ -324,57 +324,60 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
             raise ValueError(
                 f"cache extension chunk ({S} tokens) exceeds the KV ring "
                 f"capacity ({C}): in-chunk positions would alias ring slots")
-        pos_b = positions if positions.ndim == 2 \
-            else jnp.broadcast_to(positions[None], (B, S))
-        slots = pos_b % C                                   # (B, S)
-        bidx = jnp.arange(B)[:, None]
-        if q_valid is not None:
-            # ragged rows: pad entries re-write the ring's current contents
-            # (slots within a row are distinct — S <= C enforced above and
-            # positions are consecutive — so the masked scatter is
-            # deterministic)
-            kw = jnp.where(q_valid[..., None, None], k,
-                           cache.k[bidx, slots])
-            vw = jnp.where(q_valid[..., None, None], v,
-                           cache.v[bidx, slots])
-            pw = jnp.where(q_valid, pos_b, cache.positions[bidx, slots])
-        else:
-            kw, vw, pw = k, v, pos_b
-        kc = cache.k.at[bidx, slots].set(kw)
-        vc = cache.v.at[bidx, slots].set(vw)
-        pc = cache.positions.at[bidx, slots].set(pw)
-        new_cache = KVCache(k=kc, v=vc, positions=pc)
-        if S > 1 and window:
-            # SWA carry-window extension: a chunk landing at offset o
-            # recycles ring slots (capacity = window) that still hold
-            # in-window keys needed by the chunk's own earliest queries —
-            # attending against the POST-write ring would silently drop
-            # them.  Attend instead against the PRE-write ring CARRIED
-            # alongside the chunk's own keys: the ring holds positions
-            # o-C..o-1 (a superset of every in-window key the chunk can
-            # see), the chunk contributes o..o+S-1, and the two position
-            # sets are disjoint, so the window mask selects exactly the
-            # right keys.  Pad rows' chunk keys are masked out (-1) so a
-            # short row can only see its own live ring.  The RING is still
-            # written through the masked scatter above — eviction there is
-            # correct (decode never looks back past the window).
-            kp_chunk = pos_b if q_valid is None \
-                else jnp.where(q_valid, pos_b, -1)
-            ka = jnp.concatenate([cache.k, k], axis=1)
-            va = jnp.concatenate([cache.v, v], axis=1)
-            pa = jnp.concatenate([cache.positions, kp_chunk], axis=1)
-        else:
-            ka, va, pa = kc, vc, pc
-        # decode: the cache is sequence-sharded (context parallelism); keep
-        # that layout — repeating kv heads is fine, but constraining heads
-        # onto the model axis here would force a full cache reshard.
-        if scheme == "repeat":
-            g = cfg.n_heads // max(cfg.n_kv_heads, 1)
-            if g > 1:
-                ka = jnp.repeat(ka, g, axis=2)
-                va = jnp.repeat(va, g, axis=2)
-        ka = constrain(ka, "b", "tp", None, None)
-        va = constrain(va, "b", "tp", None, None)
+        with jax.named_scope("kv_ring"):
+            pos_b = positions if positions.ndim == 2 \
+                else jnp.broadcast_to(positions[None], (B, S))
+            slots = pos_b % C                                   # (B, S)
+            bidx = jnp.arange(B)[:, None]
+            if q_valid is not None:
+                # ragged rows: pad entries re-write the ring's current
+                # contents (slots within a row are distinct — S <= C
+                # enforced above and positions are consecutive — so the
+                # masked scatter is deterministic)
+                kw = jnp.where(q_valid[..., None, None], k,
+                               cache.k[bidx, slots])
+                vw = jnp.where(q_valid[..., None, None], v,
+                               cache.v[bidx, slots])
+                pw = jnp.where(q_valid, pos_b, cache.positions[bidx, slots])
+            else:
+                kw, vw, pw = k, v, pos_b
+            kc = cache.k.at[bidx, slots].set(kw)
+            vc = cache.v.at[bidx, slots].set(vw)
+            pc = cache.positions.at[bidx, slots].set(pw)
+            new_cache = KVCache(k=kc, v=vc, positions=pc)
+            if S > 1 and window:
+                # SWA carry-window extension: a chunk landing at offset o
+                # recycles ring slots (capacity = window) that still hold
+                # in-window keys needed by the chunk's own earliest queries
+                # — attending against the POST-write ring would silently
+                # drop them.  Attend instead against the PRE-write ring
+                # CARRIED alongside the chunk's own keys: the ring holds
+                # positions o-C..o-1 (a superset of every in-window key the
+                # chunk can see), the chunk contributes o..o+S-1, and the
+                # two position sets are disjoint, so the window mask
+                # selects exactly the right keys.  Pad rows' chunk keys are
+                # masked out (-1) so a short row can only see its own live
+                # ring.  The RING is still written through the masked
+                # scatter above — eviction there is correct (decode never
+                # looks back past the window).
+                kp_chunk = pos_b if q_valid is None \
+                    else jnp.where(q_valid, pos_b, -1)
+                ka = jnp.concatenate([cache.k, k], axis=1)
+                va = jnp.concatenate([cache.v, v], axis=1)
+                pa = jnp.concatenate([cache.positions, kp_chunk], axis=1)
+            else:
+                ka, va, pa = kc, vc, pc
+            # decode: the cache is sequence-sharded (context parallelism);
+            # keep that layout — repeating kv heads is fine, but
+            # constraining heads onto the model axis here would force a
+            # full cache reshard.
+            if scheme == "repeat":
+                g = cfg.n_heads // max(cfg.n_kv_heads, 1)
+                if g > 1:
+                    ka = jnp.repeat(ka, g, axis=2)
+                    va = jnp.repeat(va, g, axis=2)
+            ka = constrain(ka, "b", "tp", None, None)
+            va = constrain(va, "b", "tp", None, None)
         out = flash_attention(q, ka, va, pos_b, pa, causal=causal,
                               window=window, chunk=cfg.attn_chunk)
     else:
@@ -391,48 +394,52 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
                                   causal=causal and not cross, window=0,
                                   chunk=cfg.attn_chunk)
         if return_cache:
-            # Build the ring cache from the last kept positions (slot =
-            # pos % C, for any C).  The ring
-            # is sized for the TARGET sequence length (cache_len), not the
-            # prompt, so subsequent decode steps never clobber live slots.
-            C = Skv if cross else cache_capacity(cfg, cache_len or int(Skv))
-            if q_valid is not None and not cross:
-                # Ragged stacked prefill: the last C COLUMNS of a padded
-                # batch are pads for a short row — slicing them (below)
-                # would evict that row's real in-window keys.  Build each
-                # row's ring by a per-(row, slot) GATHER of its last
-                # min(C, L) VALID positions instead: slot s's owner is the
-                # largest valid position congruent to s mod C.
-                lengths = jnp.sum(q_valid.astype(jnp.int32), axis=1)  # (B,)
-                s_idx = jnp.arange(C, dtype=jnp.int32)[None]          # (1,C)
-                last = lengths[:, None] - 1                           # (B,1)
-                owner = last - ((last - s_idx) % C)                   # (B,C)
-                valid = (owner >= 0) & (lengths[:, None] > 0)
-                col = jnp.clip(owner, 0, Skv - 1)[..., None, None]
-                kb = jnp.take_along_axis(k, col, axis=1)
-                vb = jnp.take_along_axis(v, col, axis=1)
-                new_cache = KVCache(
-                    k=jnp.where(valid[..., None, None], kb, 0),
-                    v=jnp.where(valid[..., None, None], vb, 0),
-                    positions=jnp.where(valid, owner, -1))
-            else:
-                # The kept positions p0..p0+n_keep-1 are consecutive (the
-                # pass's own arange), so slot s holds kept entry
-                # (s - p0) mod C: a GATHER, like the ragged branch.  The TPU
-                # compiler aborts on a scatter into a zero ring in a program
-                # that also holds a decode step's ring write (``generate``).
-                n_keep = min(C, Skv)
-                p0 = kv_pos[Skv - n_keep].astype(jnp.int32)
-                j = (jnp.arange(C, dtype=jnp.int32) - p0) % C        # (C,)
-                has = j < n_keep
-                src = Skv - n_keep + jnp.minimum(j, n_keep - 1)
-                kb = jnp.take(k, src, axis=1)
-                vb = jnp.take(v, src, axis=1)
-                new_cache = KVCache(
-                    k=jnp.where(has[None, :, None, None], kb, 0),
-                    v=jnp.where(has[None, :, None, None], vb, 0),
-                    positions=jnp.broadcast_to(
-                        jnp.where(has, p0 + j, -1)[None], (B, C)))
+            with jax.named_scope("kv_ring"):
+                # Build the ring cache from the last kept positions (slot =
+                # pos % C, for any C).  The ring is sized for the TARGET
+                # sequence length (cache_len), not the prompt, so subsequent
+                # decode steps never clobber live slots.
+                C = Skv if cross \
+                    else cache_capacity(cfg, cache_len or int(Skv))
+                if q_valid is not None and not cross:
+                    # Ragged stacked prefill: the last C COLUMNS of a padded
+                    # batch are pads for a short row — slicing them (below)
+                    # would evict that row's real in-window keys.  Build each
+                    # row's ring by a per-(row, slot) GATHER of its last
+                    # min(C, L) VALID positions instead: slot s's owner is the
+                    # largest valid position congruent to s mod C.
+                    lengths = jnp.sum(q_valid.astype(jnp.int32),
+                                      axis=1)                        # (B,)
+                    s_idx = jnp.arange(C, dtype=jnp.int32)[None]     # (1,C)
+                    last = lengths[:, None] - 1                      # (B,1)
+                    owner = last - ((last - s_idx) % C)              # (B,C)
+                    valid = (owner >= 0) & (lengths[:, None] > 0)
+                    col = jnp.clip(owner, 0, Skv - 1)[..., None, None]
+                    kb = jnp.take_along_axis(k, col, axis=1)
+                    vb = jnp.take_along_axis(v, col, axis=1)
+                    new_cache = KVCache(
+                        k=jnp.where(valid[..., None, None], kb, 0),
+                        v=jnp.where(valid[..., None, None], vb, 0),
+                        positions=jnp.where(valid, owner, -1))
+                else:
+                    # The kept positions p0..p0+n_keep-1 are consecutive
+                    # (the pass's own arange), so slot s holds kept entry
+                    # (s - p0) mod C: a GATHER, like the ragged branch.  The
+                    # TPU compiler aborts on a scatter into a zero ring in a
+                    # program that also holds a decode step's ring write
+                    # (``generate``).
+                    n_keep = min(C, Skv)
+                    p0 = kv_pos[Skv - n_keep].astype(jnp.int32)
+                    j = (jnp.arange(C, dtype=jnp.int32) - p0) % C        # (C,)
+                    has = j < n_keep
+                    src = Skv - n_keep + jnp.minimum(j, n_keep - 1)
+                    kb = jnp.take(k, src, axis=1)
+                    vb = jnp.take(v, src, axis=1)
+                    new_cache = KVCache(
+                        k=jnp.where(has[None, :, None, None], kb, 0),
+                        v=jnp.where(has[None, :, None, None], vb, 0),
+                        positions=jnp.broadcast_to(
+                            jnp.where(has, p0 + j, -1)[None], (B, C)))
 
     out = constrain(out, "b", None, "tp", None)
     y = apply_dense(p["wo"], out.reshape(B, S, cfg.n_heads * hd))

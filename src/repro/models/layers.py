@@ -87,11 +87,13 @@ def init_lm_head(cfg, key) -> Params:
 
 
 def lm_logits(head: Params, embed: Params, x: jax.Array, cfg) -> jax.Array:
-    if cfg.tie_embeddings:
-        return jnp.einsum("...d,vd->...v", x, embed["embedding"],
+    """The unembedding, under the ``logits`` named scope."""
+    with jax.named_scope("logits"):
+        if cfg.tie_embeddings:
+            return jnp.einsum("...d,vd->...v", x, embed["embedding"],
+                              preferred_element_type=x.dtype)
+        return jnp.einsum("...d,dv->...v", x, head["w"],
                           preferred_element_type=x.dtype)
-    return jnp.einsum("...d,dv->...v", x, head["w"],
-                      preferred_element_type=x.dtype)
 
 
 # ---------------------------------------------------------------- dense

@@ -11,7 +11,8 @@ executes against cached termination tables and block geometry (digit planes
 themselves are derived in-kernel per call, never cached or materialized);
 unprepared params fall back to trace-time lowering.  The runtime precision comes from the active
 ``repro.runtime`` precision scope (per-request budgets in serving), and
-termination statistics are surfaced through ``repro.models.stats``.
+termination statistics are surfaced through ``repro.models.stats``.  The
+up-projection, on either path, runs under the ``mlp_up`` named scope.
 """
 
 from __future__ import annotations
@@ -43,11 +44,11 @@ def apply_mlp(p: Params, x: jax.Array, cfg) -> jax.Array:
     act = _ACTS[cfg.act]
     if cfg.dslot.enabled and cfg.act == "relu" and not cfg.glu:
         return _apply_mlp_dslot(p, x, cfg)
-    up = constrain(apply_dense(p["up"], x), "b", None, "tp")
-    if cfg.glu:
-        h = act(constrain(apply_dense(p["gate"], x), "b", None, "tp")) * up
-    else:
-        h = act(up)
+    with jax.named_scope("mlp_up"):
+        up = constrain(apply_dense(p["up"], x), "b", None, "tp")
+        gate = constrain(apply_dense(p["gate"], x), "b", None, "tp") \
+            if cfg.glu else None
+    h = act(gate) * up if cfg.glu else act(up)
     return apply_dense(p["down"], h)
 
 
@@ -72,7 +73,8 @@ def _apply_mlp_dslot(p: Params, x: jax.Array, cfg) -> jax.Array:
     from . import stats
 
     layer = _dslot_up_layer(cfg)
-    h, st = layer.apply(p["up"], x.astype(jnp.float32))
+    with jax.named_scope("mlp_up"):
+        h, st = layer.apply(p["up"], x.astype(jnp.float32))
     stats.record("mlp_dslot_skipped_frac", st.skipped_frac)
     stats.record("mlp_dslot_planes_used",
                  jnp.mean(st.planes_used.astype(jnp.float32)))

@@ -41,6 +41,20 @@ from .ssm import SSMState, apply_ssm, init_ssm
 
 # ------------------------------------------------------------- single layer
 
+# Named scopes: the ops of each sub-layer carry ``attn/`` or ``mlp/`` in
+# their HLO ``op_name`` (inside the layer scan's body too), so a profile
+# attributes device time by layer; inputs (the norms) stay outside.
+
+def _attn(p: Params, x: jax.Array, cfg, **kw):
+    with jax.named_scope("attn"):
+        return attention_forward(p, x, cfg, **kw)
+
+
+def _mlp(p: Params, x: jax.Array, cfg) -> jax.Array:
+    with jax.named_scope("mlp"):
+        return apply_mlp(p, x, cfg)
+
+
 def init_layer(cfg, key, kind: str) -> Params:
     ks = jax.random.split(key, 4)
     if kind == "ssm":
@@ -118,12 +132,12 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
                                    return_state=return_cache or use_cache,
                                    q_valid=q_valid)
         x = x + h
-        x = x + apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+        x = x + _mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
         return x, new_state, aux
 
     if kind == "attn_cross":
         self_cache, cross_cache = cache if cache is not None else (None, None)
-        h, new_self = attention_forward(
+        h, new_self = _attn(
             p["attn"], apply_norm(p["norm1"], x, cfg), cfg,
             positions=positions, cache=self_cache if use_cache else None,
             causal=causal, return_cache=return_cache, cache_len=cache_len,
@@ -131,21 +145,21 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
         x = x + h
         if use_cache:
             # decode: static cross cache built at prefill
-            h, cross_cache = attention_forward(
+            h, cross_cache = _attn(
                 p["cross"], apply_norm(p["normx"], x, cfg), cfg,
                 positions=positions, cache=cross_cache, is_cross=True,
                 causal=False)
         else:
-            h, cross_cache = attention_forward(
+            h, cross_cache = _attn(
                 p["cross"], apply_norm(p["normx"], x, cfg), cfg,
                 positions=positions, kv_x=enc_out, causal=False,
                 return_cache=return_cache)
         x = x + h
-        x = x + apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+        x = x + _mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
         return x, (new_self, cross_cache), aux
 
     # attn / moe
-    h, new_cache = attention_forward(
+    h, new_cache = _attn(
         p["attn"], apply_norm(p["norm1"], x, cfg), cfg, positions=positions,
         cache=cache if use_cache else None, causal=causal,
         return_cache=return_cache, cache_len=cache_len, q_valid=q_valid)
@@ -153,7 +167,7 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
     if kind == "moe":
         h, aux = apply_moe(p["moe"], apply_norm(p["norm2"], x, cfg), cfg)
     else:
-        h = apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+        h = _mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
     return x + h, new_cache, aux
 
 

@@ -83,6 +83,7 @@ from typing import Callable, Iterator
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import AxisType, NamedSharding, PartitionSpec
 import numpy as np
 
@@ -617,16 +618,19 @@ class ServeEngine:
         vectors keep live slots undisturbed) and decode from THIS step
         on."""
         for task in self.pipeline.tick(self._free_slot):
-            i = task.slot
-            self.state = _merge_slot(self.state, task.state, i)
-            self.slot_req[i] = task.req
-            task.req.phase = DECODING
-            self._acc_planes[i] = 0.0
-            self._acc_bounded[i] = 0.0
-            self._acc_steps[i] = 0
-            # first token through the engine's sample fn (greedy by default),
-            # matching what ``generate`` does with its prefill logits
-            self.next_tok[i] = int(jax.device_get(self.sample(task.logits)[0]))
+            with TraceAnnotation("serve.merge", uid=task.req.uid):
+                i = task.slot
+                self.state = _merge_slot(self.state, task.state, i)
+                self.slot_req[i] = task.req
+                task.req.phase = DECODING
+                self._acc_planes[i] = 0.0
+                self._acc_bounded[i] = 0.0
+                self._acc_steps[i] = 0
+                # first token through the engine's sample fn (greedy by
+                # default), matching what ``generate`` does with its
+                # prefill logits
+                self.next_tok[i] = int(
+                    jax.device_get(self.sample(task.logits)[0]))
 
     def _evict_timeouts(self) -> int:
         """Deadline sweep: evict every request past its deadline — queued,
@@ -696,17 +700,42 @@ class ServeEngine:
         the pool one step with state untouched — both leave the engine in a
         state where ``check_invariants()`` passes and the next ``step()``
         proceeds.
+
+        Each phase is a ``jax.profiler`` host span (``serve.step`` holding
+        ``serve.admit``, ``serve.launch``, ``serve.readback`` and
+        ``serve.emit``; ``docs/serving.md``, "Observability"): recorded
+        only while a profiler trace is active, on the device events' clock.
         """
         if self._closed:
             raise RuntimeError("ServeEngine is closed")
         self._steps += 1
-        inj = self.injector
-        if inj is not None:
-            inj.begin_step(self._steps)
-            for f in inj.slow_steps():            # artificial latency
-                time.sleep(f.value or 0.0)
-            for uid in inj.cancels():             # replayable cancel storms
-                self.cancel(uid)
+        with StepTraceAnnotation("serve.step", step_num=self._steps):
+            inj = self.injector
+            if inj is not None:
+                inj.begin_step(self._steps)
+                for f in inj.slow_steps():        # artificial latency
+                    time.sleep(f.value or 0.0)
+                for uid in inj.cancels():         # replayable cancel storms
+                    self.cancel(uid)
+            with TraceAnnotation("serve.admit"):
+                self._admit(inj)
+            if all(r is None for r in self.slot_req):
+                return []
+            with TraceAnnotation("serve.launch"):
+                budgets, decoded = self._launch(inj)
+            if decoded is None:
+                # decode failed every retry: state/tokens/accounting
+                # untouched, the pool stalls exactly one step and retries
+                # next step
+                return []
+            with TraceAnnotation("serve.readback"):
+                nxt, fin, rows, bounded = self._readback(decoded, budgets,
+                                                         inj)
+            with TraceAnnotation("serve.emit"):
+                return self._emit(nxt, fin, rows, bounded)
+
+    def _admit(self, inj) -> None:
+        """Deadline sweep, the admission tick (retried), SLO update."""
         timed_out = self._evict_timeouts()
         f0 = self.pipeline.forwards
         for _ in range(self.cfg.max_step_retries + 1):
@@ -733,23 +762,26 @@ class ServeEngine:
                 planes_used_mean=self._last_rows_mean,
                 timed_out=timed_out))
             self._ttft_obs = []
-        if all(r is None for r in self.slot_req):
-            return []
+
+    def _launch(self, inj):
+        """Budgets, the token input and the pooled decode dispatch
+        (retried); ``decoded`` is None when every retry raised."""
         toks = jnp.asarray(self.next_tok[:, None])
         budgets = self._budget_vector()
-        decoded = None
         for _ in range(self.cfg.max_step_retries + 1):
             try:
                 if inj is not None:
                     inj.raise_if("decode_forward")
-                decoded = self._decode(self.params, self.state, toks, budgets)
-                break
+                return budgets, self._decode(self.params, self.state, toks,
+                                             budgets)
             except Exception as e:  # noqa: BLE001
                 self.errors.append((self._steps, "decode", repr(e)))
-        if decoded is None:
-            # decode failed every retry: state/tokens/accounting untouched,
-            # the pool stalls exactly one step and retries next step
-            return []
+        return budgets, None
+
+    def _readback(self, decoded, budgets, inj):
+        """Commit the decoded state and fetch what the host needs: the
+        budgets, the finite guard, the sampled tokens and the planes
+        account."""
         logits, state2, aux = decoded
         self.last_budget = np.asarray(jax.device_get(budgets))
         poisoned = False
@@ -770,6 +802,11 @@ class ServeEngine:
         bounded = float(jax.device_get(aux["bounded"])) \
             if "bounded" in aux else None
         self._last_rows_mean = None if rows is None else float(rows.mean())
+        return nxt, fin, rows, bounded
+
+    def _emit(self, nxt, fin, rows, bounded) -> list[Request]:
+        """Emit each live slot's token (quarantining poisoned slots) and
+        retire finished requests."""
         finished = []
         for i, req in enumerate(self.slot_req):
             if req is None:

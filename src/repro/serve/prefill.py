@@ -79,6 +79,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.models.attention import cache_capacity
 from repro.runtime import precision_scope
@@ -336,28 +337,32 @@ class PrefillPipeline:
         """Advance ``targets`` by one chunk in ONE stacked forward; returns
         the tasks whose prompt is now fully in (extracted from their
         lanes).  Non-target lanes ride along with zero-length rows —
-        ``q_valid`` masking makes them exact no-ops on the lane state."""
-        L = self.lanes
-        c = self.chunk if self.chunk > 0 \
-            else max(t.remaining for t in targets)
-        toks = np.zeros((L, c), np.int32)
-        lens = np.zeros((L,), np.int32)
-        npl = np.full((L,), self._resolve_precision(None), np.int32)
-        for t in targets:
-            end = min(t.offset + c, len(t.req.prompt))
-            n = end - t.offset
-            toks[t.lane, :n] = t.req.prompt[t.offset:end]
-            lens[t.lane] = n
-            npl[t.lane] = self._resolve_precision(t.req)
-        if self.injector is not None:
-            # fault hook: a raise here leaves the tick transactional — no
-            # task offset moved, the lane state untouched (the forward is a
-            # functional update), so the engine's retry re-runs this exact
-            # chunk against this exact state.
-            self.injector.raise_if("lane_forward")
-        logits, self._lane_state = self._extend_lanes(
-            self.params, self._lane_state, jnp.asarray(toks),
-            jnp.asarray(lens), jnp.asarray(npl))
+        ``q_valid`` masking makes them exact no-ops on the lane state.
+        Building the inputs and dispatching the forward is the
+        ``serve.prefill`` host span, tagged with the targets' uids."""
+        with TraceAnnotation("serve.prefill", uids=",".join(
+                str(t.req.uid) for t in targets)):
+            L = self.lanes
+            c = self.chunk if self.chunk > 0 \
+                else max(t.remaining for t in targets)
+            toks = np.zeros((L, c), np.int32)
+            lens = np.zeros((L,), np.int32)
+            npl = np.full((L,), self._resolve_precision(None), np.int32)
+            for t in targets:
+                end = min(t.offset + c, len(t.req.prompt))
+                n = end - t.offset
+                toks[t.lane, :n] = t.req.prompt[t.offset:end]
+                lens[t.lane] = n
+                npl[t.lane] = self._resolve_precision(t.req)
+            if self.injector is not None:
+                # fault hook: a raise here leaves the tick transactional — no
+                # task offset moved, the lane state untouched (the forward is a
+                # functional update), so the engine's retry re-runs this exact
+                # chunk against this exact state.
+                self.injector.raise_if("lane_forward")
+            logits, self._lane_state = self._extend_lanes(
+                self.params, self._lane_state, jnp.asarray(toks),
+                jnp.asarray(lens), jnp.asarray(npl))
         self.forwards += 1
         completed: list[PrefillTask] = []
         for t in targets:

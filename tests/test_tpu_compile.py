@@ -16,6 +16,7 @@ file must collect the same tests without loading it.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +98,19 @@ def test_kernel_compiles_for_v5e(one_chip, on_tpu, wdtype, block_k):
             _sds((), jnp.int32, one_chip),
             _sds((M,), jnp.int32, one_chip)).compile()
     _assert_kernel(compiled)
+
+
+def test_kernel_call_carries_its_trace_name(one_chip, on_tpu):
+    # the benchmark reads the kernel's device time by this name
+    K, N = OLMO
+    compiled = jax.jit(lambda q, w: dslot_matmul_pallas(q, w).out).lower(
+        _sds((128, K), jnp.uint8, one_chip),
+        _sds((K, N), jnp.float32, one_chip)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert all(re.match(r"\s*(ROOT )?%dslot_matmul_pallas(\.\d+)? = ", line)
+               for line in calls), calls
 
 
 def test_dslot_generate_compiles_for_v5e(one_chip, on_tpu):
