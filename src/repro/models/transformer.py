@@ -10,8 +10,9 @@ Layers are stacked per pattern position and iterated with ``jax.lax.scan``
 regardless of depth — a 95-layer model compiles as one scanned block.  The
 pattern remainder (e.g. recurrentgemma's 26 = 3*8 + 2) runs unscanned.
 
-Caches are pytrees mirroring the parameter stacking, so decode steps scan
-with the same structure.  ``mode="decode"`` accepts multi-token inputs too:
+Caches are pytrees mirroring the parameter stacking.  A decode step carries
+the stacked KV rings through the scan and writes each layer's new entries
+in place; recurrent states ride the scan's inputs and outputs.  ``mode="decode"`` accepts multi-token inputs too:
 attention writes each chunk's KV at its positions into the per-sequence
 rings — batched, at ragged per-sequence offsets, with ``q_valid`` masking
 the ring writes of right-padded rows — and recurrent mixers advance their
@@ -30,8 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from . import stats as model_stats
-from .attention import (KVCache, attention_forward, init_attention,
-                        init_kv_cache)
+from .attention import attention_forward, init_attention, init_kv_cache
 from .layers import Params, apply_norm, init_norm
 from .mlp import apply_mlp, init_mlp
 from .moe import apply_moe, init_moe
@@ -91,14 +91,8 @@ def init_layer_cache(cfg, kind: str, batch: int, seq_len: int,
                           h=jnp.zeros((batch, w), jnp.float32))
     self_cache = init_kv_cache(cfg, batch, seq_len, dtype)
     if kind == "attn_cross":
-        hd = cfg.head_dim_
-        cross = KVCache(
-            k=jnp.zeros((batch, enc_len, cfg.n_kv_heads, hd), dtype),
-            v=jnp.zeros((batch, enc_len, cfg.n_kv_heads, hd), dtype),
-            positions=jnp.broadcast_to(
-                jnp.arange(enc_len, dtype=jnp.int32)[None],
-                (batch, enc_len)))
-        return (self_cache, cross)
+        return (self_cache,
+                init_kv_cache(cfg, batch, enc_len, dtype, cross=True))
     return self_cache
 
 
@@ -106,7 +100,8 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
                 positions: jax.Array, cache: Any = None,
                 enc_out: jax.Array | None = None, mode: str = "train",
                 causal: bool = True, cache_len: int | None = None,
-                q_valid: jax.Array | None = None
+                q_valid: jax.Array | None = None,
+                layer: jax.Array | None = None
                 ) -> tuple[jax.Array, Any, jax.Array]:
     """Returns (x, new_cache, aux_loss).
 
@@ -114,6 +109,8 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
     the KV-ring write in attention kinds (see ``attention_forward``) and
     are exact identity steps in the recurrent mixers (``apply_ssm`` /
     ``apply_rglru``), so carried state only ever advances past real tokens.
+    ``layer``: the self-attention ring's index in a stacked (scanned)
+    ring cache; the cache then is, and the returned one is, the stack.
     """
     aux = jnp.zeros((), jnp.float32)
     return_cache = mode == "prefill"
@@ -141,7 +138,7 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
             p["attn"], apply_norm(p["norm1"], x, cfg), cfg,
             positions=positions, cache=self_cache if use_cache else None,
             causal=causal, return_cache=return_cache, cache_len=cache_len,
-            q_valid=q_valid)
+            q_valid=q_valid, layer=layer)
         x = x + h
         if use_cache:
             # decode: static cross cache built at prefill
@@ -162,7 +159,8 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
     h, new_cache = _attn(
         p["attn"], apply_norm(p["norm1"], x, cfg), cfg, positions=positions,
         cache=cache if use_cache else None, causal=causal,
-        return_cache=return_cache, cache_len=cache_len, q_valid=q_valid)
+        return_cache=return_cache, cache_len=cache_len, q_valid=q_valid,
+        layer=layer)
     x = x + h
     if kind == "moe":
         h, aux = apply_moe(p["moe"], apply_norm(p["norm2"], x, cfg), cfg)
@@ -235,26 +233,48 @@ class Stack:
         new_caches = {"groups": [], "rest": []}
 
         if self.n_groups:
-            def group_body(x, layer_inputs):
-                params_g, caches_g = layer_inputs
+            # Decode: each attention kind's stacked ring rides the scan's
+            # CARRY, and every layer writes only its new entries into the
+            # stack in place, at its group index (``layer``).  Passed as
+            # xs/ys instead, each step would slice every layer's whole ring
+            # out of the stack and write it back.  Recurrent states (small)
+            # and the static cross caches go through xs; only the recurrent
+            # states come back as ys.
+            carried = caches is not None
+            rings, per_layer = None, None
+            if carried:
+                rings, per_layer = zip(*(
+                    _split_cache(kind, c)
+                    for kind, c in zip(self.pattern, caches["groups"])))
+
+            def group_body(carry, layer_inputs):
+                x, rings = carry
+                params_g, g, per_g = layer_inputs
                 aux_g = jnp.zeros((), jnp.float32)
-                new_cs = []
+                rings = list(rings) if carried else None
+                outs = []
                 # Layer statistics recorded inside a scanned body would be
                 # scan-local tracers; capture them here and thread them out
                 # as scan outputs, re-recording the stacked values after the
                 # scan — makes the stats side channel scan-safe.
                 with model_stats.collect() as sink:
                     for pos, kind in enumerate(self.pattern):
-                        c = None if caches_g is None else caches_g[pos]
+                        c = _join_cache(kind, rings[pos], per_g[pos]) \
+                            if carried else None
                         x, nc, aux = apply_layer(
                             params_g[pos], x, cfg, kind, positions=positions,
                             cache=c, enc_out=enc_out, mode=mode,
                             causal=self.causal, cache_len=cache_len,
-                            q_valid=q_valid)
-                        new_cs.append(nc)
+                            q_valid=q_valid, layer=g if carried else None)
+                        if carried:
+                            rings[pos], nc = _split_cache(kind, nc)
+                            if kind == "attn_cross":
+                                nc = None        # read only: not carried out
+                        outs.append(nc)
                         aux_g = aux_g + aux
                 recs = {k: tuple(v) for k, v in sink.items()}
-                return x, (tuple(new_cs), aux_g, recs)
+                rings = tuple(rings) if carried else None
+                return (x, rings), (tuple(outs), aux_g, recs)
 
             body = group_body
             if cfg.remat and mode == "train":
@@ -262,25 +282,16 @@ class Stack:
                     group_body,
                     policy=jax.checkpoint_policies.nothing_saveable)
 
-            caches_g = None
-            if caches is not None:
-                caches_g = tuple(caches["groups"])
-            xs = (tuple(p["groups"]), caches_g)
-            if caches_g is None:
-                xs = (tuple(p["groups"]), None)
-
-            def scan_body(x, inp):
-                return body(x, inp)
-
-            if caches_g is None:
-                # scan only over params
-                def scan_body_np(x, params_g):
-                    return body(x, (params_g, None))
-                x, (ncs, auxs, recs) = jax.lax.scan(scan_body_np, x,
-                                                    tuple(p["groups"]))
-                new_caches["groups"] = list(ncs) if mode == "prefill" else []
-            else:
-                x, (ncs, auxs, recs) = jax.lax.scan(scan_body, x, xs)
+            (x, rings), (ncs, auxs, recs) = jax.lax.scan(
+                body, (x, rings),
+                (tuple(p["groups"]), jnp.arange(self.n_groups), per_layer))
+            if carried:
+                new_caches["groups"] = [
+                    _join_cache(kind, ring,
+                                per if kind == "attn_cross" else nc)
+                    for kind, ring, per, nc in zip(self.pattern, rings,
+                                                   per_layer, ncs)]
+            elif mode == "prefill":
                 new_caches["groups"] = list(ncs)
             aux_total = aux_total + jnp.sum(auxs)
             for k, vals in recs.items():
@@ -298,3 +309,23 @@ class Stack:
             aux_total = aux_total + aux
 
         return x, new_caches, aux_total
+
+
+def _split_cache(kind: str, cache):
+    """A layer cache as (self-attention ring, the rest): the rest is the
+    static cross cache of ``attn_cross``, a recurrent kind's state, or
+    None."""
+    if kind in ("attn", "moe"):
+        return cache, None
+    if kind == "attn_cross":
+        return cache
+    return None, cache
+
+
+def _join_cache(kind: str, ring, rest):
+    """Inverse of ``_split_cache``."""
+    if kind in ("attn", "moe"):
+        return ring
+    if kind == "attn_cross":
+        return (ring, rest)
+    return rest

@@ -150,6 +150,29 @@ def _collapse_bounded(sink: dict) -> jax.Array | None:
     return jnp.mean(jnp.stack(vals))
 
 
+def decode_program(model: Model, n_slots: int):
+    """The engine's pooled decode step, jitted as ``jit__decode``:
+    ``(params, state, tokens (n_slots, 1), budgets (n_slots,)) -> (logits,
+    state, aux)``.  The state is donated: each ring's new tokens are written
+    in place (the output aliases the input), so the caller must drop the
+    state it passed and keep the one returned."""
+
+    def _decode(p, st, t, npl):
+        with stats_channel.collect() as sink, precision_scope(npl):
+            lg, st2 = model.decode_step(p, st, t)
+        rows = _collapse_rows(sink, n_slots)
+        bnd = _collapse_bounded(sink)
+        aux = {} if rows is None else {"rows": rows}
+        if bnd is not None:
+            aux["bounded"] = bnd
+        # per-slot non-finite detection, fused into the step (one reduce)
+        # — the quarantine guard reads it on the host
+        aux["finite"] = jnp.all(jnp.isfinite(lg), axis=-1)
+        return lg, st2, aux
+
+    return jax.jit(_decode, donate_argnums=(1,))
+
+
 def generate(model: Model, params, batch: dict, max_new_tokens: int,
              *, max_len: int | None = None, sample=greedy_sample,
              key=None, n_planes=None, return_stats: bool | None = None
@@ -379,20 +402,7 @@ class ServeEngine:
             dslot=self.dslot, calibrated=self.calibrated,
             injector=self.injector)
 
-        def _decode(p, st, t, npl):
-            with stats_channel.collect() as sink, precision_scope(npl):
-                lg, st2 = model.decode_step(p, st, t)
-            rows = _collapse_rows(sink, self.n_slots)
-            bnd = _collapse_bounded(sink)
-            aux = {} if rows is None else {"rows": rows}
-            if bnd is not None:
-                aux["bounded"] = bnd
-            # per-slot non-finite detection, fused into the step (one
-            # reduce) — the quarantine guard reads it on the host
-            aux["finite"] = jnp.all(jnp.isfinite(lg), axis=-1)
-            return lg, st2, aux
-
-        self._decode = jax.jit(_decode)
+        self._decode = decode_program(model, self.n_slots)
 
     @property
     def serve_config(self) -> ServeConfig:
@@ -765,24 +775,27 @@ class ServeEngine:
 
     def _launch(self, inj):
         """Budgets, the token input and the pooled decode dispatch
-        (retried); ``decoded`` is None when every retry raised."""
+        (retried), committing the new state as it returns: the call
+        donates the old one.  ``decoded`` (logits, aux) is None when every
+        retry raised; a try that raises before the call leaves the state
+        intact for the next."""
         toks = jnp.asarray(self.next_tok[:, None])
         budgets = self._budget_vector()
         for _ in range(self.cfg.max_step_retries + 1):
             try:
                 if inj is not None:
                     inj.raise_if("decode_forward")
-                return budgets, self._decode(self.params, self.state, toks,
-                                             budgets)
+                logits, self.state, aux = self._decode(
+                    self.params, self.state, toks, budgets)
+                return budgets, (logits, aux)
             except Exception as e:  # noqa: BLE001
                 self.errors.append((self._steps, "decode", repr(e)))
         return budgets, None
 
     def _readback(self, decoded, budgets, inj):
-        """Commit the decoded state and fetch what the host needs: the
-        budgets, the finite guard, the sampled tokens and the planes
-        account."""
-        logits, state2, aux = decoded
+        """Fetch what the host needs: the budgets, the finite guard, the
+        sampled tokens and the planes account."""
+        logits, aux = decoded
         self.last_budget = np.asarray(jax.device_get(budgets))
         poisoned = False
         if inj is not None:
@@ -792,7 +805,6 @@ class ServeEngine:
             fin = np.asarray(jax.device_get(
                 jnp.all(jnp.isfinite(logits), axis=-1) if poisoned
                 else aux["finite"]))
-        self.state = state2
         if inj is not None:
             for slot in inj.kv_corruptions(self._fault_slot):
                 self.state = self._corrupt_slot(self.state, slot)

@@ -145,9 +145,16 @@ def test_ragged_extension_pad_rows_never_clobber_the_ring(lm):
     toks[0, 0] = 7
     lg_r, st_r = model.extend(params, st, jnp.asarray(toks),
                               lengths=jnp.asarray([1], np.int32))
-    # reference: the same single token, unpadded
-    lg_1, st_1 = model.extend(params, st, jnp.asarray([[7]], np.int32))
+    # reference: the same token padded by one, whose phantom (position 15)
+    # lands on the ring's one empty slot.  Both run the chunked attention
+    # path; a single token would decode through ``ring_attention``, whose
+    # contractions sum in another order.
+    lg_1, st_1 = model.extend(params, st, jnp.asarray([[7, 0]], np.int32),
+                              lengths=jnp.asarray([1], np.int32))
     assert np.array_equal(np.asarray(lg_r), np.asarray(lg_1))
+    lg_d, _ = model.extend(params, st, jnp.asarray([[7]], np.int32))
+    np.testing.assert_allclose(np.asarray(lg_r), np.asarray(lg_d),
+                               rtol=1e-5, atol=1e-5)
     assert np.asarray(st_r["pos"]).tolist() == [15]
     for got, ref in zip(jax.tree.leaves(st_r["caches"]),
                         jax.tree.leaves(st_1["caches"])):

@@ -244,6 +244,41 @@ def test_decode_exception_stalls_then_recovers_exact(lm):
     assert 3 not in r.token_steps
 
 
+def test_decode_donates_the_pool(lm):
+    """The pooled decode program donates the engine's state: after a step
+    the state it was handed is deleted (its rings were written in place)
+    and ``eng.state`` is the live one the step returned."""
+    _, model, params = lm
+    eng = ServeEngine(model, params, ServeConfig(
+        n_slots=2, max_len=64, prefill_chunk=8))
+    r = Request(uid=1, prompt=_prompt(6, 8), max_new=5)
+    assert eng.try_add(r)
+    eng.step()                                  # admits, then decodes
+    old = eng.state
+    eng.step()
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(old))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(eng.state))
+    _drive(eng, [r])
+    assert r.out == _solo(model, params, 6, 8, 5)
+
+
+def test_decode_exception_retried_before_donation_exact(lm):
+    """A decode forward that raises once is retried within the same step:
+    the fault fires before the call, so the retry runs on the intact pool
+    and the stream equals a fault-free run, with no step skipped."""
+    _, model, params = lm
+    plan = FaultPlan(faults=(Fault(kind="decode_exception", step=3,
+                                   count=1),))
+    eng = ServeEngine(model, params, ServeConfig(
+        n_slots=1, max_len=64, prefill_chunk=8, faults=plan))
+    r = Request(uid=1, prompt=_prompt(6, 31), max_new=6)
+    assert eng.try_add(r)
+    _drive(eng, [r])
+    assert r.out == _solo(model, params, 6, 31, 6)
+    assert [e[:2] for e in eng.errors] == [(3, "decode")]
+    assert r.token_steps == list(range(1, 7))
+
+
 def test_admission_exhaustion_fails_inflight_only(lm):
     """Admission raising past every retry evicts the in-flight tasks as
     FAILED so the lanes recover; the engine keeps serving afterwards."""

@@ -7,7 +7,9 @@ not an interpreted loop.  Widths are the up-projections ``chip_smoke.py``
 runs on the chip: olmo-1b (K=2048, N=8192) and seamless-m4t-medium
 (K=1024, N=4096), at decode (8) and prefill (2048) rows; one whole
 ``generate`` program at a reduced width holds the kernel inside the model.
-Interpret-mode tests cannot see a block Mosaic refuses; these can.
+Interpret-mode tests cannot see a block Mosaic refuses; these can.  The
+serving engine's pooled decode program, at both benchmark cells' widths,
+holds no whole-ring temporary and writes its KV pool in place.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a test worker that is not given this
@@ -23,12 +25,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.base import DslotConfig
+from repro.configs.base import DslotConfig, ModelConfig
 from repro.configs.registry import ARCHS
 from repro.kernels.dslot_matmul import dslot_matmul_pallas
 from repro.kernels.ops import dslot_execute, dslot_prepare
 from repro.models.model_zoo import build_model
 from repro.serve import generate
+from repro.serve.engine import decode_program
 
 OLMO = (2048, 8192)
 SEAMLESS = (1024, 4096)
@@ -147,3 +150,46 @@ def test_unaligned_block_refused_when_compiled(blocks, shape, name):
         dslot_matmul_pallas(q, w, interpret=False, **blocks)
     out = dslot_matmul_pallas(q, w, interpret=True, **blocks).out
     assert jnp.allclose(out, 0.01 * K)
+
+
+# The benchmark cells' published widths (OPT-1.3b, arXiv:2205.01068; OLMo-1B,
+# arXiv:2402.00838), each with its slot count and context, as the engine
+# serves them.
+OPT_1P3B = dict(
+    name="opt-1.3b", family="dense", n_layers=24, d_model=2048, n_heads=32,
+    n_kv_heads=32, head_dim=64, d_ff=8192, vocab_size=50272, qkv_bias=True,
+    norm="layernorm", act="relu", glu=False, tie_embeddings=True,
+    scan_unroll=2, dslot=DslotConfig(enabled=True, use_pallas=True,
+                                     block_m=128, block_n=128,
+                                     act_scale=0.03))
+OLMO_1B = dict(
+    name="olmo-1b", family="dense", n_layers=16, d_model=2048, n_heads=16,
+    n_kv_heads=16, head_dim=128, d_ff=8192, vocab_size=50304,
+    norm="nonparam_ln", act="silu", glu=True, tie_embeddings=True)
+
+
+@pytest.mark.parametrize("widths,slots", [(OPT_1P3B, 8), (OLMO_1B, 16)],
+                         ids=["opt-1.3b", "olmo-1b"])
+def test_pooled_decode_writes_the_ring_in_place(one_chip, on_tpu, widths,
+                                                slots):
+    # one decode step reads each layer's ring where it lies and writes only
+    # the new tokens: no temporary as large as one layer's K ring, and the
+    # donated pool's K and V come back aliased to their input
+    max_len = 2048
+    cfg = ModelConfig(**widths)
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    if cfg.dslot.enabled:
+        params = jax.eval_shape(model.prepare_dslot, params)
+    state = jax.eval_shape(lambda: model.init_decode_state(slots, max_len))
+    sds = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                       (params, state))
+    compiled = decode_program(model, slots).lower(
+        *sds, _sds((slots, 1), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    ring = slots * max_len * cfg.n_kv_heads * cfg.head_dim * 2   # bf16 K
+    assert mem.temp_size_in_bytes < ring, mem
+    assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * ring, mem
+    if cfg.dslot.enabled:
+        _assert_kernel(compiled)
