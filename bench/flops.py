@@ -1,4 +1,7 @@
-"""Operations and bytes that each counted piece of work needs, from shapes.
+"""Operations and bytes that each counted piece of work needs, from shapes,
+and the least time the chip could take for them.  A whole token's model
+operations are the architecture module's (``arch/<name>.py``,
+``decode_token_flops`` and ``prefill_flops``).
 
 These are the work the model requires, not what an implementation happens
 to do: a kernel that pads rows, re-streams weights per digit plane or runs
@@ -25,33 +28,3 @@ def least_time(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
     if t_compute >= t_memory:
         return t_compute, "compute"
     return t_memory, "memory"
-
-
-def matmul_params(m: dict) -> tuple[int, int]:
-    """(parameters of the matmuls in all layers, of the output head)."""
-    d, hd = m["d_model"], m.get("head_dim") or m["d_model"] // m["n_heads"]
-    attn = d * hd * (2 * m["n_heads"] + 2 * m["n_kv_heads"])
-    mlp = d * m["d_ff"] * (3 if m["glu"] else 2)
-    return m["n_layers"] * (attn + mlp), d * m["vocab_size"]
-
-
-def attention_flops(m: dict, ctx: int) -> float:
-    """Scores and value mixing of one query against ``ctx`` keys."""
-    hd = m.get("head_dim") or m["d_model"] // m["n_heads"]
-    return 4.0 * m["n_layers"] * ctx * m["n_heads"] * hd
-
-
-def decode_token_flops(m: dict, ctx: int) -> float:
-    """One generated token whose query sees ``ctx`` keys (itself included)."""
-    body, head = matmul_params(m)
-    return 2.0 * (body + head) + attention_flops(m, ctx)
-
-
-def prefill_flops(m: dict, prompt_len: int) -> float:
-    """A whole prompt: every position through the layers, causal attention,
-    and the head once (only the last position's logits are needed)."""
-    body, head = matmul_params(m)
-    hd = m.get("head_dim") or m["d_model"] // m["n_heads"]
-    causal_pairs = prompt_len * (prompt_len + 1) / 2.0
-    attn = 4.0 * m["n_layers"] * causal_pairs * m["n_heads"] * hd
-    return 2.0 * body * prompt_len + attn + 2.0 * head

@@ -33,16 +33,17 @@ import serving  # noqa: E402
 
 def readings(found: dict, seed: int, seconds: float) -> dict:
     cfg, traffic, nums = found["config"], found["traffic"], found["numbers"]
+    arch = run.arch_of(cfg)
     t = time.perf_counter()
-    scale = serving.act_step(cfg, seed)
-    eng = serving.build_engine(cfg, seed, scale)
+    scale = serving.act_step(cfg, arch, seed)
+    eng = serving.build_engine(cfg, arch, seed, scale)
     serving.warm_up(eng, cfg)
     w = serving.run_window(eng, cfg, traffic, seed, seconds,
                            settle_tokens=nums["check_tokens"])
     n_failed = serving.failed(w)
     serving.free(eng)
-    ref = serving.check(cfg, traffic, seed, w, nums["check_tokens"], scale,
-                        with_control=True)
+    ref = serving.check(cfg, arch, traffic, seed, w, nums["check_tokens"],
+                        scale, with_control=True)
     control = dict(ref, mean_logit_gap=ref["control_mean_logit_gap"])
     return {"seed": seed, "failed": n_failed, **ref,
             "correct": run.verdict(ref, n_failed, nums)[0],

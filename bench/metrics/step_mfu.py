@@ -10,5 +10,5 @@ def read(run):
     lo, hi = w.trace_t if w.trace_t else (w.t0, w.t1)
     if hi <= lo:
         return None
-    ops = serving.work_in(w, run.model, lo, hi)
+    ops = serving.work_in(w, run.arch, run.model, lo, hi)
     return 100.0 * ops / ((hi - lo) * run.peaks["bf16_flops"])

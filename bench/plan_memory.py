@@ -4,12 +4,14 @@ described TPU v5e with no chip attached.
     JAX_PLATFORMS=cpu python bench/plan_memory.py <config> [n_slots ...]
 
 For each pool width it compiles the engine's two programs at the
-configuration's widths (the pooled decode step over ``n_slots`` rows, and
-the batched lane extend over ``chunks_per_step`` lanes of ``prefill_chunk``
-tokens) and prints ``compiled.memory_analysis()`` with an estimate of the
-step's peak: the program's arguments, outputs and temporaries (no serving
-program donates its state), plus the decode state that stays live beside
-it (the lane pool beside a decode step, the slot pool beside an extend).
+configuration's widths (the pooled decode step over ``n_slots`` rows, the
+engine's own ``decode_program``, and the batched lane extend over
+``chunks_per_step`` lanes of ``prefill_chunk`` tokens) and prints
+``compiled.memory_analysis()`` with an estimate of the step's peak: the
+program's arguments, outputs and temporaries, less what its outputs alias
+(the decode step donates its pool, so the pool it returns is the one it
+was given), plus the decode state that stays live beside it (the lane
+pool beside a decode step, the slot pool beside an extend).
 Nothing runs, so it is a plan; ``memory_peak_bytes`` of a chip run is the
 measurement.
 """
@@ -40,6 +42,7 @@ def plan(config: str, pools: list[int]) -> list[dict]:
     import serving
     from repro.models.model_zoo import build_model
     from repro.runtime import precision_scope
+    from repro.serve.engine import decode_program
 
     with open(os.path.join(BENCH, "configs", f"{config}.json")) as f:
         cfg = json.load(f)
@@ -59,10 +62,6 @@ def plan(config: str, pools: list[int]) -> list[dict]:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
             tree)
 
-    def decode(p, st, t, npl):
-        with precision_scope(npl):
-            return model.decode_step(p, st, t)
-
     def extend(p, st, t, lens, npl):
         with precision_scope(npl):
             return model.extend(p, st, t, lengths=lens)
@@ -81,24 +80,28 @@ def plan(config: str, pools: list[int]) -> list[dict]:
     out = []
     for n in pools:
         pool = jax.eval_shape(lambda: model.init_decode_state(n, s["max_len"]))
-        dec = jax.jit(decode).lower(
+        dec = decode_program(model, n).lower(
             sds(params), sds(pool),
             jax.ShapeDtypeStruct((n, 1), i32, sharding=chip),
             jax.ShapeDtypeStruct((n,), i32, sharding=chip)).compile()
         dm = dec.memory_analysis()
         side = _bytes(fresh)
         d_peak = (dm.argument_size_in_bytes + dm.output_size_in_bytes
-                  + dm.temp_size_in_bytes + _bytes(lane_st) + side)
+                  - dm.alias_size_in_bytes + dm.temp_size_in_bytes
+                  + _bytes(lane_st) + side)
         e_peak = (em.argument_size_in_bytes + em.output_size_in_bytes
-                  + em.temp_size_in_bytes + _bytes(pool) + side)
+                  - em.alias_size_in_bytes + em.temp_size_in_bytes
+                  + _bytes(pool) + side)
         out.append({"config": config, "n_slots": n,
                     "params_bytes": _bytes(params),
                     "pool_bytes": _bytes(pool),
                     "decode": {"args": dm.argument_size_in_bytes,
                                "out": dm.output_size_in_bytes,
+                               "alias": dm.alias_size_in_bytes,
                                "temp": dm.temp_size_in_bytes},
                     "extend": {"args": em.argument_size_in_bytes,
                                "out": em.output_size_in_bytes,
+                               "alias": em.alias_size_in_bytes,
                                "temp": em.temp_size_in_bytes},
                     "peak_estimate_bytes": max(d_peak, e_peak)})
     return out

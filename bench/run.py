@@ -3,7 +3,8 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``: the
-configuration in ``bench/configs/<config>.json``, the traffic mix in
+configuration in ``bench/configs/<config>.json`` and the architecture
+module it names in ``bench/arch/<arch>.py``, the traffic mix in
 ``bench/traffic/<mix>.json``, the cell's own numbers (the tokens checked
 and the correctness limit) in ``bench/cells/<cell>.json``, and each per-layer
 metric's reader in ``bench/metrics/<metric>.py``.
@@ -25,6 +26,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -104,6 +106,26 @@ def reader(name: str):
     return mod.read
 
 
+def arch_of(cfg: dict):
+    """The architecture module that the configuration names by its
+    ``arch`` key, ``bench/arch/<arch>.py``; there is no default."""
+    name = cfg.get("arch")
+    if not name:
+        raise Refused(f"configuration {cfg.get('name')!r} names no arch")
+    path = os.path.join(BENCH, "arch", f"{name}.py")
+    if not os.path.isfile(path):
+        raise Refused(f"no architecture module {path}")
+    return _module(path, f"bench_arch_{name}")
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def memory_peak() -> int:
     import jax
     peak = 0
@@ -126,13 +148,14 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
 
     found = found or find_cell(cell_name)
     cfg, traffic, nums = found["config"], found["traffic"], found["numbers"]
+    arch = arch_of(cfg)
     device, peaks = device_check(found["cell"]["chips"])
     import jax
     log(f"device {device} jax {jax.__version__} cache "
         f"{enable_cache() if cache else 'off'}")
 
-    scale = serving.act_step(cfg, seed)
-    eng = serving.build_engine(cfg, seed, scale)
+    scale = serving.act_step(cfg, arch, seed)
+    eng = serving.build_engine(cfg, arch, seed, scale)
     serving.warm_up(eng, cfg)
     jax.block_until_ready(eng.state)
     setup_s = time.perf_counter() - T_START
@@ -163,7 +186,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     log("steps in the window (ms): " + serving.step_summary(w))
 
     t_ref = time.perf_counter()
-    ref = serving.check(cfg, traffic, seed, w, nums["check_tokens"], scale)
+    ref = serving.check(cfg, arch, traffic, seed, w, nums["check_tokens"],
+                        scale)
     log(f"reference over {ref['requests']} requests, {ref['tokens']} served "
         f"tokens, top-1 agreement {ref['top1_agreement']:.4f}, widest gap "
         f"{ref['max_logit_gap']:.4f}, {time.perf_counter() - t_ref:.1f}s")
@@ -183,7 +207,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     if trace:
         rec = serving.RunRecord(cell=cell_name, cfg=cfg, traffic=traffic,
                                 peaks=peaks, window=w, trace=tr,
-                                results=results)
+                                results=results, arch=arch)
         for m in found["per_layer"]:
             v = reader(m["name"])(rec)
             if v is not None:

@@ -15,7 +15,6 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-import flops
 import traffic_gen
 import weights
 
@@ -23,11 +22,15 @@ clock = time.perf_counter
 
 
 def model_config(cfg: dict, scale: float | None):
+    """The program's ``ModelConfig``; JSON lists (a ``block_pattern``)
+    become the tuples the frozen, hashed config holds."""
     from repro.configs.base import DslotConfig, ModelConfig
     d = dict(cfg.get("dslot") or {})
     dslot = DslotConfig(**d, act_scale=scale) if d.get("enabled") \
         else DslotConfig()
-    return ModelConfig(**cfg["model"], dslot=dslot)
+    model = {k: tuple(v) if isinstance(v, list) else v
+             for k, v in cfg["model"].items()}
+    return ModelConfig(**model, dslot=dslot)
 
 
 def uses_dslot(cfg: dict) -> bool:
@@ -35,21 +38,21 @@ def uses_dslot(cfg: dict) -> bool:
     return bool(d.get("enabled") and m["act"] == "relu" and not m["glu"])
 
 
-def act_step(cfg: dict, seed: int) -> float | None:
+def act_step(cfg: dict, arch, seed: int) -> float | None:
     if not uses_dslot(cfg):
         return None
-    return weights.act_scale(cfg["model"], seed, cfg["calibration"],
+    return weights.act_scale(arch, cfg["model"], seed, cfg["calibration"],
                              cfg["dslot"]["n_bits"])
 
 
-def build_engine(cfg: dict, seed: int, scale: float | None):
+def build_engine(cfg: dict, arch, seed: int, scale: float | None):
     """The served model with the benchmark's weights, behind a
     ``ServeEngine`` built from the configuration's ``ServeConfig``."""
     from repro.models.model_zoo import build_model
     from repro.serve import ServeConfig, ServeEngine
 
     model = build_model(model_config(cfg, scale))
-    params = weights.program_params(model, cfg["model"], seed)
+    params = weights.program_params(model, arch, cfg["model"], seed)
     return ServeEngine(model, params, ServeConfig(**cfg["serve"]))
 
 
@@ -231,15 +234,16 @@ def failed(w: Window) -> int:
     return w.rejected + sum(r.phase in _EVICTED for r in w.reqs.values())
 
 
-def check(cfg: dict, traffic: dict, seed: int, w: Window, min_tokens: int,
-          scale: float | None, with_control: bool = False) -> dict:
+def check(cfg: dict, arch, traffic: dict, seed: int, w: Window,
+          min_tokens: int, scale: float | None,
+          with_control: bool = False) -> dict:
     """The reference's readings over a sample of the window's finished
     requests (``sample_for_check``), each at its granted plane budget."""
     import reference
     sample = [(r.prompt, list(r.out), r.n_planes or 8)
               for r in sample_for_check(w, seed, min_tokens)]
     return reference.compare(
-        cfg["model"], cfg["dslot"] if uses_dslot(cfg) else None, seed,
+        arch, cfg["model"], cfg["dslot"] if uses_dslot(cfg) else None, seed,
         sample, pad_to=-(-traffic_gen.max_total(traffic) // 128) * 128,
         step=scale or 1.0, with_control=with_control) | {
             "requests": len(sample)}
@@ -263,18 +267,18 @@ def sample_for_check(w: Window, seed: int, min_tokens: int) -> list:
     return picked
 
 
-def work_in(w: Window, m: dict, lo: float, hi: float) -> float:
-    """Model operations of the tokens emitted and prompts claimed in
-    [lo, hi]: the prompt when it was claimed into a lane, each output token
-    at its context length."""
+def work_in(w: Window, arch, m: dict, lo: float, hi: float) -> float:
+    """Model operations, as the architecture module counts them, of the
+    tokens emitted and prompts claimed in [lo, hi]: the prompt when it was
+    claimed into a lane, each output token at its context length."""
     ops = 0.0
     for uid, req in w.reqs.items():
         c = w.claimed.get(uid)
         if c is not None and lo <= c <= hi:
-            ops += flops.prefill_flops(m, len(req.prompt))
+            ops += arch.prefill_flops(m, len(req.prompt))
         for j, t in enumerate(w.tok_t[uid]):
             if lo <= t <= hi and j > 0:
-                ops += flops.decode_token_flops(m, len(req.prompt) + j)
+                ops += arch.decode_token_flops(m, len(req.prompt) + j)
     return ops
 
 
@@ -288,6 +292,7 @@ class RunRecord:
     window: Window
     trace: object = None            # devtrace.Trace, when traced
     results: list = field(default_factory=list)   # GenerateResults
+    arch: object = None             # the configuration's arch module
 
     @property
     def model(self) -> dict:

@@ -107,13 +107,14 @@ def test_up_proj_work_counts_dense_rows_unpadded():
 
 def test_model_flops_per_token():
     with open(os.path.join(BENCH, "configs", "opt-1.3b.json")) as f:
-        m = json.load(f)["model"]
-    body, head = flops.matmul_params(m)
+        cfg = json.load(f)
+    m, dense = cfg["model"], run.arch_of(cfg)
+    body, head = dense.matmul_params(m)
     assert body == 24 * (4 * 2048 * 2048 + 2 * 2048 * 8192)
     assert head == 2048 * 50272
-    assert flops.decode_token_flops(m, 1) == 2 * (body + head) \
+    assert dense.decode_token_flops(m, 1) == 2 * (body + head) \
         + 4 * 24 * 2048
-    assert flops.prefill_flops(m, 2) == 2 * body * 2 + 4 * 24 * 3 * 2048 \
+    assert dense.prefill_flops(m, 2) == 2 * body * 2 + 4 * 24 * 3 * 2048 \
         + 2 * head
 
 

@@ -95,7 +95,9 @@ def test_each_bench_step_holds_the_engines_own_step_span(tmp_path):
 
     found = tiny.cell()
     cfg = found["config"]
-    eng = serving.build_engine(cfg, SEED, serving.act_step(cfg, SEED))
+    arch = run.arch_of(cfg)
+    eng = serving.build_engine(cfg, arch, SEED,
+                               serving.act_step(cfg, arch, SEED))
     serving.warm_up(eng, cfg)
     serving.run_window(eng, cfg, found["traffic"], SEED, 2.0,
                        trace_dir=str(tmp_path), trace_s=1.0)

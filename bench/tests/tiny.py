@@ -30,10 +30,7 @@ def cell(config: str = "opt-1.3b", mix: str = "decode",
         cfg["dslot"] = {"enabled": False}
     cfg["serve"].update(n_slots=4, max_len=96, prefill_chunk=16,
                         chunks_per_step=2)
-    traffic = _load("traffic", f"{mix}.json")
-    traffic["prompt_tokens"].update(median=12, min=4, max=40)
-    traffic["output_tokens"] = {"dist": "uniform", "min": 4, "max": 16}
-    traffic["pool"] = 256
+    traffic = small_traffic(_load("traffic", f"{mix}.json"))
     name = f"{config}.{mix}"
     spec = _load("..", "BENCHMARK.json")
     return {"cell": {"name": name, "chips": 1},
@@ -44,6 +41,14 @@ def cell(config: str = "opt-1.3b", mix: str = "decode",
                            if name in m.get("workloads", [name])],
             "per_layer": [m for m in spec["per_layer"]
                           if name in m.get("workloads", [name])]}
+
+
+def small_traffic(traffic: dict) -> dict:
+    """A mix's lengths cut to what a tiny engine holds (``max_len`` 96)."""
+    traffic["prompt_tokens"].update(median=12, min=4, max=40)
+    traffic["output_tokens"] = {"dist": "uniform", "min": 4, "max": 16}
+    traffic["pool"] = 256
+    return traffic
 
 
 def no_chip(chips: int):
