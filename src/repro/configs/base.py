@@ -43,17 +43,26 @@ class ModelConfig:
     window: int = 0                  # swa / local-attn window
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    rope: bool = True                # False: no position embedding (NoPE)
     norm: str = "rmsnorm"            # rmsnorm | nonparam_ln | layernorm
-    act: str = "silu"                # silu | gelu | relu
+    norm_eps: float = 1e-6           # every norm's epsilon, SSM's gated one too
+    act: str = "silu"                # silu | gelu | relu | relu2
     glu: bool = True
     # moe
-    n_experts: int = 0
+    n_experts: int = 0               # the router's width: experts routed over
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training only; serving is dropless
+    moe_router: str = "softmax"      # softmax | sigmoid (+ correction bias)
+    moe_scale: float = 1.0           # routed weights' scale after top-k norm
+    moe_shared_ff: int = 0           # shared expert's width (0: none)
+    experts_held: int = 0            # experts held here (0: all), from
+    expert_offset: int = 0           # this one on: an expert-parallel share
     # ssm (mamba2)
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_headdim: int = 64
+    ssm_heads: int = 0               # 0 -> ssm_expand * d_model // headdim
+    ssm_groups: int = 1              # B/C groups (and gated-norm groups)
     ssm_conv: int = 4
     ssm_chunk: int = 256
     # hybrid block pattern, tiled to n_layers (e.g. RG-LRU 1:2)
@@ -79,6 +88,10 @@ class ModelConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def experts_held_(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def sub_quadratic(self) -> bool:

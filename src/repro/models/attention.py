@@ -55,6 +55,11 @@ def _layer_of(ring: jax.Array, layer) -> jax.Array:
     return ring if layer is None else ring[layer]
 
 
+def _rope(x: jax.Array, positions: jax.Array, cfg) -> jax.Array:
+    """Rotary positions, unless the configuration has none (``rope``)."""
+    return apply_rope(x, positions, cfg.rope_theta) if cfg.rope else x
+
+
 def init_attention(cfg, key, cross: bool = False) -> Params:
     dt = jnp.dtype(cfg.dtype)
     hd = cfg.head_dim_
@@ -82,10 +87,12 @@ def init_kv_cache(cfg, batch: int, seq_len: int, dtype, *,
     (-1 positions); ``cross``: a static encoder cache of ``seq_len``
     entries, every position valid."""
     C = seq_len if cross else cache_capacity(cfg, seq_len)
-    zeros = jnp.zeros((batch, C, cfg.n_kv_heads * cfg.head_dim_), dtype)
+    shape = (batch, C, cfg.n_kv_heads * cfg.head_dim_)
     pos = jnp.arange(C, dtype=jnp.int32) if cross \
         else jnp.full((C,), -1, jnp.int32)
-    return KVCache(k=zeros, v=zeros,
+    # K and V in buffers of their own: a program that donates the cache
+    # cannot take one buffer twice
+    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    positions=jnp.broadcast_to(pos[None], (batch, C)))
 
 
@@ -324,8 +331,8 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
     B, S, _ = x.shape
     hd = cfg.head_dim_
     cross = is_cross or kv_x is not None
-    q = apply_dense(p["wq"], x).reshape(B, S, cfg.n_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
+    q = _rope(apply_dense(p["wq"], x).reshape(B, S, cfg.n_heads, hd),
+              positions, cfg)
 
     if cross and cache is not None and kv_x is None:
         # decode against a static (encoder) cross cache: no writes, no mask
@@ -347,12 +354,8 @@ def attention_forward(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
     k = apply_dense(p["wk"], src).reshape(B, Skv, cfg.n_kv_heads, hd)
     v = apply_dense(p["wv"], src).reshape(B, Skv, cfg.n_kv_heads, hd)
 
-    if not cross:
-        k = apply_rope(k, positions, cfg.rope_theta)
-        kv_pos = positions
-    else:
-        kv_pos = jnp.arange(Skv, dtype=jnp.int32)
-        k = apply_rope(k, kv_pos, cfg.rope_theta)
+    kv_pos = positions if not cross else jnp.arange(Skv, dtype=jnp.int32)
+    k = _rope(k, kv_pos, cfg)
 
     # Shard attention across the model axis (pspec.py):
     # "kv" shards kv heads; "repeat" duplicates kv to q-heads so the head

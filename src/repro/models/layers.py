@@ -34,11 +34,12 @@ def apply_norm(p: Params, x: jax.Array, cfg) -> jax.Array:
     saved-carry stacks in the layer scan stay bf16, DESIGN.md §5)."""
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
-        r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+        r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
         return x * (r.astype(x.dtype)) * p["scale"].astype(x.dtype)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
-    r = jax.lax.rsqrt(var + 1e-6)
+    r = jax.lax.rsqrt(var + cfg.norm_eps)
     out = (x - mu.astype(x.dtype)) * r.astype(x.dtype)
     if cfg.norm == "layernorm":
         out = out * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)

@@ -27,6 +27,7 @@ _ACTS = {
     "silu": jax.nn.silu,
     "gelu": jax.nn.gelu,
     "relu": lambda x: jnp.maximum(x, 0.0),
+    "relu2": lambda x: jnp.square(jnp.maximum(x, 0.0)),   # squared ReLU
 }
 
 

@@ -29,12 +29,12 @@ class SSMState(NamedTuple):
 
 
 def _dims(cfg):
-    d_inner = cfg.ssm_expand * cfg.d_model
+    """(d_inner, heads H, head dim P, state N, groups G): H heads of P share
+    G groups of B and C, head h the group h // (H / G).  H * P need not be
+    ``ssm_expand * d_model`` where ``ssm_heads`` says how many heads."""
     P = cfg.ssm_headdim
-    H = d_inner // P
-    N = cfg.ssm_state
-    G = 1
-    return d_inner, H, P, N, G
+    H = cfg.ssm_heads or cfg.ssm_expand * cfg.d_model // P
+    return H * P, H, P, cfg.ssm_state, cfg.ssm_groups
 
 
 def init_ssm(cfg, key) -> Params:
@@ -97,44 +97,52 @@ def _segsum(a: jax.Array) -> jax.Array:
     return jnp.where(tri, S, -jnp.inf)
 
 
+def _groups(M: jax.Array) -> jax.Array:
+    """B or C as (b, s, g, n): a (b, s, n) array is one group."""
+    return M[:, :, None] if M.ndim == 3 else M
+
+
 def ssd_chunked(x, dtv, A, B, C, chunk: int, init_state=None):
-    """Chunked SSD.  x:(b,s,h,p) dtv:(b,s,h) A:(h,) B,C:(b,s,n) [g=1].
+    """Chunked SSD.  x:(b,s,h,p) dtv:(b,s,h) A:(h,) B,C:(b,s,g,n) or
+    (b,s,n) for one group; head i reads group i // (h / g).
     Returns y:(b,s,h,p), final_state:(b,h,p,n)."""
+    B, C = _groups(B), _groups(C)
     b, s, h, pdim = x.shape
-    n = B.shape[-1]
+    g, n = B.shape[-2:]
+    k = h // g                                          # heads per group
     q = min(chunk, s)
     nc = -(-s // q)
     pad = nc * q - s
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dtv = jnp.pad(dtv, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
     a = (dtv * A[None, None, :]).astype(jnp.float32)    # (b, s', h) log decay
 
-    xc = x.reshape(b, nc, q, h, pdim).astype(jnp.float32)
-    dc = dtv.reshape(b, nc, q, h).astype(jnp.float32)
-    ac = jnp.moveaxis(a.reshape(b, nc, q, h), -1, 2)    # (b, nc, h, q)
-    Bc = B.reshape(b, nc, q, n).astype(jnp.float32)
-    Cc = C.reshape(b, nc, q, n).astype(jnp.float32)
+    xc = x.reshape(b, nc, q, g, k, pdim).astype(jnp.float32)
+    dc = dtv.reshape(b, nc, q, g, k).astype(jnp.float32)
+    ac = jnp.moveaxis(a.reshape(b, nc, q, g, k), 2, -1)  # (b, nc, g, k, q)
+    Bc = B.reshape(b, nc, q, g, n).astype(jnp.float32)
+    Cc = C.reshape(b, nc, q, g, n).astype(jnp.float32)
 
-    cs = jnp.cumsum(ac, axis=-1)                        # (b, nc, h, q) inclusive
-    L = jnp.exp(_segsum(ac))                            # (b, nc, h, q, q)
+    cs = jnp.cumsum(ac, axis=-1)                        # inclusive
+    L = jnp.exp(_segsum(ac))                            # (b, nc, g, k, q, q)
 
     # intra-chunk: y_i += sum_{j<=i} C_i·B_j L[i,j] dt_j x_j
-    scores = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)      # (b, nc, q, q)
-    w = scores[:, :, None] * L                          # (b, nc, h, q, q)
-    y_intra = jnp.einsum("bchij,bcjh,bcjhp->bcihp", w, dc, xc)
+    scores = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc)   # (b, nc, g, q, q)
+    w = scores[:, :, :, None] * L                       # (b, nc, g, k, q, q)
+    y_intra = jnp.einsum("bcgkij,bcjgk,bcjgkp->bcigkp", w, dc, xc)
 
-    # chunk states: sum_j decay_to_end[j] dt_j B_j x_j  -> (b, nc, h, p, n)
-    decay_end = jnp.exp(cs[..., -1:] - cs)              # (b, nc, h, q)
-    states = jnp.einsum("bchj,bcjh,bcjhp,bcjn->bchpn",
+    # chunk states: sum_j decay_to_end[j] dt_j B_j x_j  -> (b,nc,g,k,p,n)
+    decay_end = jnp.exp(cs[..., -1:] - cs)              # (b, nc, g, k, q)
+    states = jnp.einsum("bcgkj,bcjgk,bcjgkp,bcjgn->bcgkpn",
                         decay_end, dc, xc, Bc)
 
     # inter-chunk recurrence over nc
-    T = jnp.exp(cs[..., -1])                            # (b, nc, h) total decay
+    T = jnp.exp(cs[..., -1])                            # (b, nc, g, k)
     h0 = (jnp.zeros((b, h, pdim, n), jnp.float32) if init_state is None
-          else init_state.astype(jnp.float32))
+          else init_state.astype(jnp.float32)).reshape(b, g, k, pdim, n)
 
     def body(hprev, inp):
         Tc, sc = inp
@@ -143,37 +151,41 @@ def ssd_chunked(x, dtv, A, B, C, chunk: int, init_state=None):
 
     (hfin, hprevs) = jax.lax.scan(
         body, h0, (jnp.moveaxis(T, 1, 0), jnp.moveaxis(states, 1, 0)))
-    hprevs = jnp.moveaxis(hprevs, 0, 1)                 # (b, nc, h, p, n)
+    hprevs = jnp.moveaxis(hprevs, 0, 1)                 # (b,nc,g,k,p,n)
 
     # inter-chunk output: y_i += C_i · decay_in[i] · h_prev
     decay_in = jnp.exp(cs)                              # includes a_i
-    y_inter = jnp.einsum("bcin,bchi,bchpn->bcihp", Cc, decay_in, hprevs)
+    y_inter = jnp.einsum("bcign,bcgki,bcgkpn->bcigkp", Cc, decay_in, hprevs)
 
     y = (y_intra + y_inter).reshape(b, nc * q, h, pdim)[:, :s]
-    return y, hfin
+    return y, hfin.reshape(b, h, pdim, n)
 
 
 def ssd_sequential(x, dtv, A, B, C, init_state=None):
-    """Naive O(S) sequential recurrence — oracle for tests and decode."""
+    """Naive O(S) sequential recurrence — oracle for tests and decode.
+    Shapes as ``ssd_chunked``."""
+    B, C = _groups(B), _groups(C)
     b, s, h, pdim = x.shape
-    n = B.shape[-1]
+    g, n = B.shape[-2:]
+    k = h // g
     h0 = (jnp.zeros((b, h, pdim, n), jnp.float32) if init_state is None
-          else init_state.astype(jnp.float32))
+          else init_state.astype(jnp.float32)).reshape(b, g, k, pdim, n)
 
     def step(hprev, inp):
         xt, dt_t, Bt, Ct = inp
-        at = jnp.exp(dt_t * A)                          # (b, h)
-        upd = jnp.einsum("bh,bhp,bn->bhpn", dt_t, xt, Bt)
+        at = jnp.exp(dt_t * A.reshape(g, k))            # (b, g, k)
+        upd = jnp.einsum("bgk,bgkp,bgn->bgkpn", dt_t, xt, Bt)
         hnew = at[..., None, None] * hprev + upd
-        yt = jnp.einsum("bn,bhpn->bhp", Ct, hnew)
+        yt = jnp.einsum("bgn,bgkpn->bgkp", Ct, hnew)
         return hnew, yt
 
-    xs = (jnp.moveaxis(x.astype(jnp.float32), 1, 0),
-          jnp.moveaxis(dtv.astype(jnp.float32), 1, 0),
+    xs = (jnp.moveaxis(x.astype(jnp.float32).reshape(b, s, g, k, pdim), 1, 0),
+          jnp.moveaxis(dtv.astype(jnp.float32).reshape(b, s, g, k), 1, 0),
           jnp.moveaxis(B.astype(jnp.float32), 1, 0),
           jnp.moveaxis(C.astype(jnp.float32), 1, 0))
     hfin, ys = jax.lax.scan(step, h0, xs)
-    return jnp.moveaxis(ys, 0, 1), hfin
+    return (jnp.moveaxis(ys, 0, 1).reshape(b, s, h, pdim),
+            hfin.reshape(b, h, pdim, n))
 
 
 def apply_ssm(p: Params, x: jax.Array, cfg, state: SSMState | None = None,
@@ -198,30 +210,36 @@ def apply_ssm(p: Params, x: jax.Array, cfg, state: SSMState | None = None,
     lengths = None if q_valid is None \
         else jnp.sum(q_valid.astype(jnp.int32), axis=1)
     conv_tail = state.conv if state is not None else None
-    xBC, new_tail = _causal_conv(xBC, p["conv_w"], p["conv_b"], conv_tail,
-                                 lengths=lengths)
+    with jax.named_scope("conv"):
+        xBC, new_tail = _causal_conv(xBC, p["conv_w"], p["conv_b"],
+                                     conv_tail, lengths=lengths)
     x_ssm, Bmat, Cmat = jnp.split(xBC, [d_inner, d_inner + G * N], axis=-1)
 
-    dtv = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
-    if q_valid is not None:
-        dtv = jnp.where(q_valid[..., None], dtv, 0.0)
-    A = -jnp.exp(p["A_log"])
-    xh = x_ssm.reshape(B_, S, H, P)
+    with jax.named_scope("scan"):
+        dtv = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
+        if q_valid is not None:
+            dtv = jnp.where(q_valid[..., None], dtv, 0.0)
+        A = -jnp.exp(p["A_log"])
+        xh = x_ssm.reshape(B_, S, H, P)
+        Bmat, Cmat = Bmat.reshape(B_, S, G, N), Cmat.reshape(B_, S, G, N)
 
-    init = state.ssm if state is not None else None
-    if sequential or S == 1:
-        y, hfin = ssd_sequential(xh, dtv, A, Bmat, Cmat, init_state=init)
-    else:
-        y, hfin = ssd_chunked(xh, dtv, A, Bmat, Cmat, cfg.ssm_chunk,
-                              init_state=init)
-    y = y + p["D_skip"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(B_, S, d_inner)
+        init = state.ssm if state is not None else None
+        if sequential or S == 1:
+            y, hfin = ssd_sequential(xh, dtv, A, Bmat, Cmat, init_state=init)
+        else:
+            y, hfin = ssd_chunked(xh, dtv, A, Bmat, Cmat, cfg.ssm_chunk,
+                                  init_state=init)
+        y = y + p["D_skip"][None, None, :, None] * xh.astype(jnp.float32)
 
-    # gated RMSNorm (mamba2): norm(y * silu(z)) * scale
-    g = y * jax.nn.silu(z.astype(jnp.float32))
-    r = jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + 1e-6)
-    g = (g * r * p["norm_scale"]).astype(x.dtype)
-
-    out = jnp.einsum("bse,ed->bsd", g, p["w_out"], preferred_element_type=g.dtype)
+    with jax.named_scope("out"):
+        # gated RMSNorm (mamba2): norm(y * silu(z)) * scale, over each of
+        # the G groups of d_inner / G channels
+        g = y.reshape(B_, S, G, d_inner // G) * jax.nn.silu(
+            z.astype(jnp.float32)).reshape(B_, S, G, d_inner // G)
+        r = jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+        g = (g * r).reshape(B_, S, d_inner) * p["norm_scale"]
+        out = jnp.einsum("bse,ed->bsd", g.astype(x.dtype), p["w_out"],
+                         preferred_element_type=x.dtype)
     new_state = SSMState(conv=new_tail, ssm=hfin) if return_state else None
     return out, new_state

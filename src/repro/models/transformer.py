@@ -3,7 +3,8 @@
 A model is a sequence of blocks drawn from the config's ``block_pattern``
 (tiled to ``n_layers``): "attn" (self-attention + MLP), "attn_cross" (adds
 cross-attention, enc-dec decoder), "moe" (attention + MoE-FFN), "ssm"
-(mamba2 mixer), "rglru" (RG-LRU mixing + MLP).
+(mamba2 mixer), "rglru" (RG-LRU mixing + MLP), and the one-mixer kinds
+"attn_only" (self-attention alone) and "moe_only" (MoE-FFN alone).
 
 Layers are stacked per pattern position and iterated with ``jax.lax.scan``
 (+ ``jax.checkpoint`` rematerialization), so the lowered HLO is O(pattern)
@@ -55,6 +56,12 @@ def _mlp(p: Params, x: jax.Array, cfg) -> jax.Array:
         return apply_mlp(p, x, cfg)
 
 
+def _moe(p: Params, x: jax.Array, cfg, mode: str, q_valid):
+    with jax.named_scope("moe"):
+        return apply_moe(p, x, cfg, q_valid=q_valid,
+                         dropless=mode != "train")
+
+
 def init_layer(cfg, key, kind: str) -> Params:
     ks = jax.random.split(key, 4)
     if kind == "ssm":
@@ -65,6 +72,10 @@ def init_layer(cfg, key, kind: str) -> Params:
     if kind == "moe":
         return {"norm1": init_norm(cfg), "attn": init_attention(cfg, ks[0]),
                 "norm2": init_norm(cfg), "moe": init_moe(cfg, ks[1])}
+    if kind == "attn_only":
+        return {"norm": init_norm(cfg), "attn": init_attention(cfg, ks[0])}
+    if kind == "moe_only":
+        return {"norm": init_norm(cfg), "moe": init_moe(cfg, ks[0])}
     if kind == "attn_cross":
         return {"norm1": init_norm(cfg), "attn": init_attention(cfg, ks[0]),
                 "normx": init_norm(cfg),
@@ -77,10 +88,9 @@ def init_layer(cfg, key, kind: str) -> Params:
 
 def init_layer_cache(cfg, kind: str, batch: int, seq_len: int,
                      enc_len: int, dtype) -> Any:
-    d_inner = cfg.ssm_expand * cfg.d_model
     if kind == "ssm":
         from .ssm import _dims
-        _, H, P, N, G = _dims(cfg)
+        d_inner, H, P, N, G = _dims(cfg)
         conv_ch = d_inner + 2 * G * N
         return SSMState(
             conv=jnp.zeros((batch, cfg.ssm_conv - 1, conv_ch), dtype),
@@ -89,6 +99,8 @@ def init_layer_cache(cfg, kind: str, batch: int, seq_len: int,
         w = cfg.rnn_width or cfg.d_model
         return RGLRUState(conv=jnp.zeros((batch, 3, w), dtype),
                           h=jnp.zeros((batch, w), jnp.float32))
+    if kind == "moe_only":
+        return None
     self_cache = init_kv_cache(cfg, batch, seq_len, dtype)
     if kind == "attn_cross":
         return (self_cache,
@@ -117,11 +129,25 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
     use_cache = mode == "decode"
 
     if kind == "ssm":
-        h, new_state = apply_ssm(p["mixer"], apply_norm(p["norm"], x, cfg),
-                                 cfg, state=cache if use_cache else None,
-                                 return_state=return_cache or use_cache,
-                                 q_valid=q_valid)
+        with jax.named_scope("ssm"):
+            h, new_state = apply_ssm(
+                p["mixer"], apply_norm(p["norm"], x, cfg), cfg,
+                state=cache if use_cache else None,
+                return_state=return_cache or use_cache, q_valid=q_valid)
         return x + h, new_state, aux
+
+    if kind == "moe_only":
+        h, aux = _moe(p["moe"], apply_norm(p["norm"], x, cfg), cfg, mode,
+                      q_valid)
+        return x + h, None, aux
+
+    if kind == "attn_only":
+        h, new_cache = _attn(
+            p["attn"], apply_norm(p["norm"], x, cfg), cfg,
+            positions=positions, cache=cache if use_cache else None,
+            causal=causal, return_cache=return_cache, cache_len=cache_len,
+            q_valid=q_valid, layer=layer)
+        return x + h, new_cache, aux
 
     if kind == "rglru":
         h, new_state = apply_rglru(p["mixer"], apply_norm(p["norm1"], x, cfg),
@@ -163,7 +189,8 @@ def apply_layer(p: Params, x: jax.Array, cfg, kind: str, *,
         layer=layer)
     x = x + h
     if kind == "moe":
-        h, aux = apply_moe(p["moe"], apply_norm(p["norm2"], x, cfg), cfg)
+        h, aux = _moe(p["moe"], apply_norm(p["norm2"], x, cfg), cfg, mode,
+                      q_valid)
     else:
         h = _mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
     return x + h, new_cache, aux
@@ -237,9 +264,11 @@ class Stack:
             # CARRY, and every layer writes only its new entries into the
             # stack in place, at its group index (``layer``).  Passed as
             # xs/ys instead, each step would slice every layer's whole ring
-            # out of the stack and write it back.  Recurrent states (small)
-            # and the static cross caches go through xs; only the recurrent
-            # states come back as ys.
+            # out of the stack and write it back.  Recurrent states ride
+            # the carry too: a layer reads its state at its group index and
+            # writes the new one back there, in place, where xs/ys would
+            # hold the whole stack twice.  The static cross caches go
+            # through xs and are not carried out.
             carried = caches is not None
             rings, per_layer = None, None
             if carried:
@@ -261,15 +290,21 @@ class Stack:
                     for pos, kind in enumerate(self.pattern):
                         c = _join_cache(kind, rings[pos], per_g[pos]) \
                             if carried else None
+                        if carried and kind in _STATE_KINDS:
+                            c = jax.tree.map(lambda a: a[g], c)
                         x, nc, aux = apply_layer(
                             params_g[pos], x, cfg, kind, positions=positions,
                             cache=c, enc_out=enc_out, mode=mode,
                             causal=self.causal, cache_len=cache_len,
                             q_valid=q_valid, layer=g if carried else None)
                         if carried:
-                            rings[pos], nc = _split_cache(kind, nc)
-                            if kind == "attn_cross":
-                                nc = None        # read only: not carried out
+                            if kind in _STATE_KINDS:
+                                nc = jax.tree.map(
+                                    lambda a, n: a.at[g].set(
+                                        n.astype(a.dtype)), rings[pos], nc)
+                            # the static cross cache is read only: nothing
+                            # goes out as ys
+                            rings[pos], nc = _split_cache(kind, nc)[0], None
                         outs.append(nc)
                         aux_g = aux_g + aux
                 recs = {k: tuple(v) for k, v in sink.items()}
@@ -287,10 +322,9 @@ class Stack:
                 (tuple(p["groups"]), jnp.arange(self.n_groups), per_layer))
             if carried:
                 new_caches["groups"] = [
-                    _join_cache(kind, ring,
-                                per if kind == "attn_cross" else nc)
-                    for kind, ring, per, nc in zip(self.pattern, rings,
-                                                   per_layer, ncs)]
+                    _join_cache(kind, ring, per)
+                    for kind, ring, per in zip(self.pattern, rings,
+                                               per_layer)]
             elif mode == "prefill":
                 new_caches["groups"] = list(ncs)
             aux_total = aux_total + jnp.sum(auxs)
@@ -311,21 +345,18 @@ class Stack:
         return x, new_caches, aux_total
 
 
+_STATE_KINDS = ("ssm", "rglru")      # a recurrent state, whole a layer
+
+
 def _split_cache(kind: str, cache):
-    """A layer cache as (self-attention ring, the rest): the rest is the
-    static cross cache of ``attn_cross``, a recurrent kind's state, or
-    None."""
-    if kind in ("attn", "moe"):
-        return cache, None
+    """A layer cache as (what rides the layer scan's carry, the rest): the
+    self-attention ring or the recurrent state, and the static cross cache
+    of ``attn_cross`` (else None)."""
     if kind == "attn_cross":
         return cache
-    return None, cache
+    return cache, None
 
 
-def _join_cache(kind: str, ring, rest):
+def _join_cache(kind: str, carried, rest):
     """Inverse of ``_split_cache``."""
-    if kind in ("attn", "moe"):
-        return ring
-    if kind == "attn_cross":
-        return (ring, rest)
-    return rest
+    return (carried, rest) if kind == "attn_cross" else carried

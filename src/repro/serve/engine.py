@@ -97,7 +97,7 @@ from repro.serve.config import ServeConfig
 from repro.serve.faults import FaultInjector
 from repro.serve.prefill import (CANCELLED, DECODING, DONE, FAILED,
                                  PREFILLING, QUARANTINED, TIMEOUT,
-                                 PrefillPipeline, _batch_axes)
+                                 PrefillPipeline, _batch_axes, _lane_ops)
 from repro.serve.result import GenerateResult
 from repro.serve.slo import STANDARD, TIERS, SloController, SloSignals
 
@@ -150,6 +150,12 @@ def _collapse_bounded(sink: dict) -> jax.Array | None:
     return jnp.mean(jnp.stack(vals))
 
 
+def _sum_records(sink: dict, key: str) -> jax.Array | None:
+    """Sum of every record under ``key`` (scan-stacked ones included)."""
+    vals = [jnp.sum(jnp.asarray(v, jnp.float32)) for v in sink.get(key, [])]
+    return jnp.sum(jnp.stack(vals)) if vals else None
+
+
 def decode_program(model: Model, n_slots: int):
     """The engine's pooled decode step, jitted as ``jit__decode``:
     ``(params, state, tokens (n_slots, 1), budgets (n_slots,)) -> (logits,
@@ -165,6 +171,12 @@ def decode_program(model: Model, n_slots: int):
         aux = {} if rows is None else {"rows": rows}
         if bnd is not None:
             aux["bounded"] = bnd
+        routed = _sum_records(sink, "moe_rows_routed")
+        if routed is not None:
+            # the expert layers' row use: rows routed to held experts, and
+            # rows their matmuls computed
+            aux["moe_rows"] = jnp.stack(
+                [routed, _sum_records(sink, "moe_rows_computed")])
         # per-slot non-finite detection, fused into the step (one reduce)
         # — the quarantine guard reads it on the host
         aux["finite"] = jnp.all(jnp.isfinite(lg), axis=-1)
@@ -380,6 +392,7 @@ class ServeEngine:
         self._acc_planes = np.zeros(self.n_slots, np.float64)
         self._acc_bounded = np.zeros(self.n_slots, np.float64)
         self._acc_steps = np.zeros(self.n_slots, np.int64)
+        self._acc_moe = np.zeros((self.n_slots, 2), np.float64)
         self._steps = 0
         self._ttft_obs: list[int] = []     # TTFTs landed since last signal
         self._last_rows_mean: float | None = None
@@ -403,6 +416,10 @@ class ServeEngine:
             injector=self.injector)
 
         self._decode = decode_program(model, self.n_slots)
+        # a finished lane's row into its slot of the pool, donated: written
+        # in place, where an eager per-leaf copy would hold the pool twice
+        self._merge = jax.jit(_lane_ops(self.pipeline._axes, jit=False)[1],
+                              donate_argnums=0)
 
     @property
     def serve_config(self) -> ServeConfig:
@@ -630,12 +647,13 @@ class ServeEngine:
         for task in self.pipeline.tick(self._free_slot):
             with TraceAnnotation("serve.merge", uid=task.req.uid):
                 i = task.slot
-                self.state = _merge_slot(self.state, task.state, i)
+                self.state = self._merge(self.state, task.state, i)
                 self.slot_req[i] = task.req
                 task.req.phase = DECODING
                 self._acc_planes[i] = 0.0
                 self._acc_bounded[i] = 0.0
                 self._acc_steps[i] = 0
+                self._acc_moe[i] = 0.0
                 # first token through the engine's sample fn (greedy by
                 # default), matching what ``generate`` does with its
                 # prefill logits
@@ -739,10 +757,10 @@ class ServeEngine:
                 # next step
                 return []
             with TraceAnnotation("serve.readback"):
-                nxt, fin, rows, bounded = self._readback(decoded, budgets,
-                                                         inj)
+                nxt, fin, rows, bounded, moe = self._readback(
+                    decoded, budgets, inj)
             with TraceAnnotation("serve.emit"):
-                return self._emit(nxt, fin, rows, bounded)
+                return self._emit(nxt, fin, rows, bounded, moe)
 
     def _admit(self, inj) -> None:
         """Deadline sweep, the admission tick (retried), SLO update."""
@@ -794,7 +812,8 @@ class ServeEngine:
 
     def _readback(self, decoded, budgets, inj):
         """Fetch what the host needs: the budgets, the finite guard, the
-        sampled tokens and the planes account."""
+        sampled tokens, the planes account and the expert rows (one fetch
+        with the tokens)."""
         logits, aux = decoded
         self.last_budget = np.asarray(jax.device_get(budgets))
         poisoned = False
@@ -808,18 +827,22 @@ class ServeEngine:
         if inj is not None:
             for slot in inj.kv_corruptions(self._fault_slot):
                 self.state = self._corrupt_slot(self.state, slot)
-        nxt = np.asarray(jax.device_get(self.sample(logits)))
+        nxt, moe = jax.device_get((self.sample(logits), aux.get("moe_rows")))
+        nxt = np.asarray(nxt)
         rows = np.asarray(jax.device_get(aux["rows"])) \
             if "rows" in aux else None
         bounded = float(jax.device_get(aux["bounded"])) \
             if "bounded" in aux else None
         self._last_rows_mean = None if rows is None else float(rows.mean())
-        return nxt, fin, rows, bounded
+        return nxt, fin, rows, bounded, moe
 
-    def _emit(self, nxt, fin, rows, bounded) -> list[Request]:
+    def _emit(self, nxt, fin, rows, bounded, moe) -> list[Request]:
         """Emit each live slot's token (quarantining poisoned slots) and
         retire finished requests."""
         finished = []
+        if moe is not None:
+            # every slot: a slot's account restarts when a request merges
+            self._acc_moe += moe
         for i, req in enumerate(self.slot_req):
             if req is None:
                 continue
@@ -928,12 +951,14 @@ class ServeEngine:
         check_invariants(self)
 
     def _result_of(self, req: Request, granted=None, used=None,
-                   skipped=None, bounded=None) -> GenerateResult:
+                   skipped=None, bounded=None,
+                   expert_rows=None) -> GenerateResult:
         return GenerateResult(
             tokens=list(req.out), n_planes=granted,
             planes_used_mean=used, skipped_frac=skipped,
             planes_bounded_mean=bounded,
             ttft_steps=req.ttft_steps,
+            expert_rows=expert_rows,
             steps=None if req.enqueue_step is None
             else self._steps - req.enqueue_step,
             phase=req.phase, uid=req.uid, tier=req.tier)
@@ -966,29 +991,10 @@ class ServeEngine:
                 self.policy.observe(fb)
             if self.slo is not None:
                 self.slo.observe(fb)
+        # (rows routed, rows computed) of the expert layers over the decode
+        # steps the slot took part in
+        moe = tuple(map(float, self._acc_moe[i])) if self._acc_moe[i, 1] \
+            else None
         req.result = self._result_of(req, granted=granted, used=used,
-                                     skipped=skipped, bounded=bounded)
-
-
-def _merge_slot(pool_state: dict, one_state: dict, slot: int) -> dict:
-    """Copy a batch-1 prefill state into row ``slot`` of the pooled state.
-
-    Works leaf-by-leaf: the batch axis of each leaf is wherever its shape
-    differs from the pooled leaf (axis 0 for plain layers and the position
-    vector, axis 1 under a leading scan-stack axis).  Only that row of the
-    pool is written, so live slots keep decoding undisturbed.
-    """
-    def merge(pool, one):
-        if pool.shape == one.shape:
-            if pool.shape and pool.shape[0] == 1:
-                return one                       # 1-slot pool: full replace
-            return pool                          # unbatched leaf: shared
-        diff = [a for a, (ps, os) in enumerate(zip(pool.shape, one.shape))
-                if ps != os]
-        if len(diff) == 1 and one.shape[diff[0]] == 1:
-            ax = diff[0]
-            idx = (slice(None),) * ax + (slice(slot, slot + 1),)
-            return pool.at[idx].set(one)
-        return pool
-
-    return jax.tree.map(merge, pool_state, one_state)
+                                     skipped=skipped, bounded=bounded,
+                                     expert_rows=moe)

@@ -38,7 +38,7 @@ Chunk mechanics (batched mode): every chunk — the first included — runs
 lane (an empty ring at position 0 extends bit-identically to a one-shot
 ``Model.prefill``: masked ring entries are healed by the online softmax).
 Lanes are **private** to their tasks — the pool is written exactly once, by
-``_merge_slot`` on completion, which replaces the reserved slot's rows with
+the engine's merge on completion, which replaces the reserved slot's rows with
 the finished lane's rows.  That makes the pipeline trivially safe against
 everything that happens to the pool in between (pooled decode steps write
 garbage KV into reserved rows exactly as they always did into free rows;

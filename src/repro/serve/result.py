@@ -44,6 +44,10 @@ class GenerateResult:
         because the prepare-time weight-side MSR bound capped the tile
         (request-independent, so a scalar on both paths; None when DSLOT
         is off or the prepared weights carry no bound).
+    expert_rows: (rows routed, rows computed) of the expert layers over
+        the decode steps the request took part in, the whole pool's: the
+        (token, held expert) rows routed, and the rows the expert matmuls
+        computed (engine path, models with expert layers; else None).
     ttft_steps: engine steps from enqueue to first token (engine path).
     steps: engine steps from enqueue to finish (engine path) or the decode
         length (batch path).
@@ -59,6 +63,7 @@ class GenerateResult:
     planes_used_mean: Any = None
     skipped_frac: Any = None
     planes_bounded_mean: Any = None
+    expert_rows: tuple[float, float] | None = None
     ttft_steps: int | None = None
     steps: int | None = None
     phase: str = "done"

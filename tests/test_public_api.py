@@ -164,7 +164,8 @@ def test_sharding_surface_pinned():
 def test_generate_result_fields_pinned():
     assert {f.name for f in GenerateResult.__dataclass_fields__.values()} == {
         "tokens", "n_planes", "planes_used_mean", "skipped_frac",
-        "planes_bounded_mean", "ttft_steps", "steps", "phase", "uid", "tier"}
+        "planes_bounded_mean", "expert_rows", "ttft_steps", "steps", "phase",
+        "uid", "tier"}
 
 
 # ------------------------------------------------------- deprecation shims
